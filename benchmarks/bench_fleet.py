@@ -55,6 +55,7 @@ ACTIVE_FRACTION = 0.25
 
 _FLEET_CODE = """
     import json, time
+    from repro.roofline.peaks import device_record
     import numpy as np
     import jax
     from repro.core.frontend import FrontendConfig
@@ -205,6 +206,7 @@ _FLEET_CODE = """
         "fleet_mw_mean": fleet_mw,
         "events_mean_sum": ev_sum,
         "event_fields": list(EventCounts._fields),
+        "device": device_record(),
     }))
 """
 
@@ -289,6 +291,7 @@ def sustained_load(n_devices: int = N_DEVICES) -> list[dict]:
             f"{r['fleet_mw_mean']:.3f} mW fleet, "
             f"traces {r['n_traces']}"
         ),
+        "device": r["device"],           # the CPU child's
     }]
     return rows
 

@@ -58,6 +58,7 @@ SPEEDUP_FLOOR = 2.0
 
 _ROLLOUT_CODE = """
     import json, time
+    from repro.roofline.peaks import device_record
     import numpy as np
     import jax
     from repro.core.frontend import FrontendConfig
@@ -147,6 +148,7 @@ _ROLLOUT_CODE = """
         "n_rollout_traces": eng.n_rollout_traces,
         "parity_bitwise": parity,
         "parity_T": PARITY_T,
+        "device": device_record(),
     }))
 """
 
@@ -243,6 +245,7 @@ def dispatch_sweep() -> list[dict]:
               f"parity bitwise at T={r['parity_T']}, traces "
               f"1+{r['n_rollout_traces']}"
         ),
+        "device": r["device"],           # the CPU child's
     }]
     return rows
 

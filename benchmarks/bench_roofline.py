@@ -103,7 +103,8 @@ def sweep_blocks() -> list[dict]:
                                 block_r=br, block_m=bm, block_k=bk)
         terms = RooflineTerms(
             flops_per_chip=model["flops"], bytes_per_chip=model["bytes"],
-            coll_bytes_per_chip=0.0)
+            coll_bytes_per_chip=0.0,
+            int8_flops_per_chip=model["int8_flops"])
         wall = _best_of(jitted, patches, idx)
         occ = terms.mxu_occupancy
         name = f"roofline_megakernel_r{br}_m{bm}_k{bk}"

@@ -570,6 +570,7 @@ def backend_delta_sweep(
 
 _MULTISTREAM_CODE = """
     import json, time
+    from repro.roofline.peaks import device_record
     import numpy as np
     import jax, jax.numpy as jnp
     from repro.core.frontend import FrontendConfig
@@ -628,6 +629,7 @@ _MULTISTREAM_CODE = """
         out[key] = best_of(lambda: eng.step(frames))
         out[key + "_traces"] = eng.n_traces
 
+    out["device"] = device_record()
     print(json.dumps(out))
 """
 
@@ -677,6 +679,8 @@ def multistream_sweep(n_devices: int = 4) -> list[dict]:
         "us_per_call": t_eng * 1e6,
         "derived": f"{speedup:.2f}x streams/s, batched engine vs sequential loop",
     })
+    for row in rows:
+        row["device"] = r["device"]      # the CPU child's, not this process's
     traces = {k: v for k, v in r.items() if k.endswith("_traces")}
     if any(v != 1 for v in traces.values()):
         raise AssertionError(f"engine recompiled during steady-state serving: {traces}")

@@ -96,7 +96,8 @@ def main(path: str = "BENCH_throughput.json") -> None:
                                 block_r=br, block_m=bm, block_k=bk)
         live = RooflineTerms(
             flops_per_chip=model["flops"], bytes_per_chip=model["bytes"],
-            coll_bytes_per_chip=0.0).as_dict()
+            coll_bytes_per_chip=0.0,
+            int8_flops_per_chip=model["int8_flops"]).as_dict()
         art = row["roofline"]["model"]
         for key, val in live.items():
             got = art.get(key)

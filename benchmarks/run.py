@@ -46,12 +46,20 @@ def main() -> None:
     else:
         modules.append(("accuracy(§1,§2.1.3,§2.1.5,Fig.4)", bench_accuracy))
 
+    from repro.roofline.peaks import device_record
+
+    # rows measured in this process name its device; the CPU-pinned
+    # child harnesses (fleet, rollout, multistream) stamp their own
+    here = device_record()
+    print(f"# device {json.dumps(here)}")
     print("name,us_per_call,derived")
     failures = 0
     results: dict[str, list[dict]] = {}
     for label, mod in modules:
         try:
             rows = mod.run()
+            for row in rows:
+                row.setdefault("device", here)
             results[label] = rows
             for row in rows:
                 print(f"{row['name']},{row['us_per_call']:.1f},{row['derived']}")

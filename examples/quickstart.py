@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import repro.core as c
 from repro.data.pipeline import SceneStream
 from repro.kernels import ops
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
@@ -58,4 +59,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 from repro import models as M
 from repro.configs import ARCH_IDS, get_config, smoke_config
 from repro.serve.serve_step import make_decode_step, make_prefill_step
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
@@ -72,4 +73,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -50,6 +50,7 @@ from repro.models.vit import ViTConfig, init_vit
 from repro.serve.engine import SaccadeEngine
 from repro.core.frontend import FrontendConfig
 from repro.core.projection import PatchSpec
+from repro.compile_cache import enable_compile_cache
 
 
 def make_cfg():
@@ -230,4 +231,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

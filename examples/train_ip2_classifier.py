@@ -21,6 +21,7 @@ from repro.core.projection import PatchSpec
 from repro.data.pipeline import SceneStream
 from repro.models.vit import ViTConfig, init_vit, vit_loss
 from repro.train.trainer import Trainer, TrainerConfig
+from repro.compile_cache import enable_compile_cache
 
 PRESETS = {
     # ~0.5M backend: trains to high accuracy on CPU in ~2 min
@@ -94,4 +95,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

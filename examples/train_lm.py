@@ -21,6 +21,7 @@ from repro.data.pipeline import DataConfig, TokenStream
 from repro.optim import AdamWConfig, init_opt_state
 from repro.train.train_step import make_train_step
 from repro.train.trainer import Trainer, TrainerConfig
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
@@ -67,4 +68,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

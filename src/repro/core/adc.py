@@ -30,6 +30,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 @dataclasses.dataclass(frozen=True)
@@ -97,11 +98,58 @@ def readout_scale_zero(
 
 
 def dequantize(
-    codes: jnp.ndarray, scale: jnp.ndarray, zero: jnp.ndarray
+    codes: jnp.ndarray, scale: jnp.ndarray, zero: jnp.ndarray,
+    code_bits: int | None = None,
 ) -> jnp.ndarray:
     """codes -> float readout: the ONE affine that is allowed to leave code
-    space (DESIGN.md §9 permits it only at the backend's first matmul)."""
-    return codes.astype(jnp.float32) * scale + zero
+    space (DESIGN.md §9 permits it only at the backend's first matmul).
+
+    The result does not depend on FMA contraction. XLA:CPU fuses a
+    multiply that feeds an add into one FMA when both land in one fused
+    loop, and which loops fuse depends on the rest of the program, so a
+    plain ``codes * scale + zero`` dequantizes the same codes one rounding
+    apart in two programs. Here the scale is split into parts narrow
+    enough that every ``codes * part`` is exact; an FMA over exact
+    products rounds as the separate add does. For codes of up to 8 bits
+    the sum of the two parts is ``codes * scale`` rounded once, the value
+    the plain affine gives without contraction. ``code_bits`` bounds the
+    codes' width. It defaults to the width of their dtype, so float codes
+    take the plain affine unless it is given."""
+    c = codes.astype(jnp.float32)
+    if code_bits is None:
+        code_bits = 8 * jnp.dtype(codes.dtype).itemsize
+    if code_bits >= 24:
+        return c * scale + zero
+    parts = _scale_parts(scale, 24 - code_bits)
+    prod = c * parts[0]
+    for part in parts[1:]:
+        prod = prod + c * part
+    return prod + zero
+
+
+def _scale_parts(scale, part_bits: int) -> list:
+    """Split a float32 ``scale`` into parts of at most ``part_bits``
+    significant bits that sum to it exactly (largest first). A concrete
+    scale, the usual case since it derives from the static ADCSpec, is
+    split on the host, so the parts enter programs and kernels as
+    constants; a traced one is split with bit masks."""
+    n_parts = -(-24 // part_bits)
+    clear = np.uint32(0xFFFFFFFF ^ ((1 << (24 - part_bits)) - 1))
+    if isinstance(scale, jax.core.Tracer):
+        rest = jnp.asarray(scale, jnp.float32)
+        as_f32 = lambda u: jax.lax.bitcast_convert_type(u, jnp.float32)
+        as_u32 = lambda f: jax.lax.bitcast_convert_type(f, jnp.uint32)
+    else:
+        rest = np.asarray(scale, np.float32)
+        as_f32 = lambda u: u.view(np.float32)
+        as_u32 = lambda f: f.view(np.uint32)
+    parts = []
+    for _ in range(n_parts - 1):
+        top = as_f32(as_u32(rest) & clear)
+        parts.append(top)
+        rest = rest - top
+    parts.append(rest)
+    return parts
 
 
 def digital_codes(

@@ -50,6 +50,8 @@ def topk_patch_indices(scores: jnp.ndarray, k: int) -> jnp.ndarray:
     n = scores.shape[-1]
     if not 1 <= k <= n:
         raise ValueError(f"k={k} out of range for {n} patches")
+    # top_k orders -0.0 below +0.0; they are one score, tied by index
+    scores = jnp.where(scores == 0, jnp.zeros_like(scores), scores)
     _, idx = jax.lax.top_k(scores, k)
     return idx.astype(jnp.int32)
 

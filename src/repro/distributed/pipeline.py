@@ -30,7 +30,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -65,10 +65,9 @@ def pipeline_forward(
             return x_out, x_out
 
         x0 = jnp.zeros(mb_shape, microbatches.dtype)
-        # newer jax requires the carry marked device-varying for shard_map's
-        # varying-manual-axes check; older releases have no pvary (and no check)
-        if hasattr(jax.lax, "pvary"):
-            x0 = jax.lax.pvary(x0, (axis,))
+        # the carry is device-varying over the stage axis (shard_map's
+        # varying-manual-axes check)
+        x0 = jax.lax.pcast(x0, (axis,), to="varying")
         _, ys = jax.lax.scan(tick, x0, jnp.arange(ticks))
         # final-stage outputs live at ticks n_stages-1 .. ticks-1
         out = jax.lax.dynamic_slice_in_dim(ys, n_stages - 1, n_micro, axis=0)

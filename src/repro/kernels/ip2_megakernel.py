@@ -42,7 +42,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ip2_project import (
-    COMPILER_PARAMS_CLS,
     IP2KernelParams,
     analog_epilogue_tile,
     pwm_quantize_tile,
@@ -65,7 +64,7 @@ def _row_map(r, rows_per_slot, block_r):
     def m(s, i, j, k, idx, cnt):
         lim = jnp.maximum(jnp.minimum(cnt[s], rows_per_slot) - 1, 0)
         pos = jnp.minimum(i * block_r + r, lim)
-        return (idx[s * rows_per_slot + pos], k)
+        return (idx[s * rows_per_slot + pos], 0, k)
 
     return m
 
@@ -124,7 +123,7 @@ def ip2_ragged_pallas(
     row_counts: jnp.ndarray,  # (S,) int32 — real rows per slot (DATA)
     patches: jnp.ndarray,     # (P_rows, K) dense pixel voltages in [0,1]
     w_q: jnp.ndarray,         # (K, M) DAC-quantized weights
-    bias: jnp.ndarray,        # (M,)
+    bias: jnp.ndarray,        # (1, M)
     params: IP2KernelParams,
     n_banks: int,
     block_r: int = 8,
@@ -144,7 +143,7 @@ def ip2_ragged_pallas(
     (R,) = row_idx.shape
     (S,) = row_counts.shape
     rps = n_banks * block_r
-    assert K == K2 and bias.shape == (M,) and R == S * rps
+    assert K == K2 and bias.shape == (1, M) and R == S * rps
     assert M % block_m == 0 and K % block_k == 0, (
         f"pad shapes to blocks: {(K, M)} vs {(block_k, block_m)}"
     )
@@ -155,10 +154,10 @@ def ip2_ragged_pallas(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=[
-            *(pl.BlockSpec((1, block_k), _row_map(r, rps, block_r))
+            *(pl.BlockSpec((None, 1, block_k), _row_map(r, rps, block_r))
               for r in range(block_r)),
             pl.BlockSpec((block_k, block_m), _w_map(block_r)),
-            pl.BlockSpec((block_m,), lambda s, i, j, k, idx, cnt: (j,)),
+            pl.BlockSpec((1, block_m), lambda s, i, j, k, idx, cnt: (0, j)),
         ],
         out_specs=pl.BlockSpec(
             (block_r, block_m),
@@ -173,13 +172,13 @@ def ip2_ragged_pallas(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, M), params.out_dtype),
-        compiler_params=COMPILER_PARAMS_CLS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")
         ),
         interpret=interpret,
     )(row_idx.astype(jnp.int32), row_counts.astype(jnp.int32),
-      *([patches] * block_r), w_q, bias)
+      *([patches[:, None, :]] * block_r), w_q, bias)
 
 
 # ---------------------------------------------------------------------------
@@ -225,18 +224,19 @@ def _fused_kernel(
     def _embed():
         @pl.when(act)
         def _active():
-            c8 = codes_ref[...].astype(jnp.int32)       # (block_r, M_pad)
-            w8 = we_ref[...].astype(jnp.int32)          # (M_pad, D_pad)
+            # int8 x int8 on the MXU with an int32 accumulator, as in
+            # quant_matmul's _qmm_kernel
+            c8 = codes_ref[...].astype(jnp.int32).astype(jnp.int8)
             acc = jax.lax.dot_general(
-                c8, w8, (((1,), (0,)), ((), ())),
+                c8, we_ref[...], (((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.DEFAULT,
                 preferred_element_type=jnp.int32,
             )
             # per-row activation scale (the ADC LSB) loaded from memory,
             # NOT baked as a constant: keeps the multiply association
             # identical to quant_matmul's _qmm_kernel (bitwise parity)
-            sa = sae_ref[...][:, None]
-            sw = swe_ref[...][None, :]
-            o_ref[...] = (acc.astype(jnp.float32) * sa * sw).astype(o_ref.dtype)
+            o_ref[...] = (acc.astype(jnp.float32) * sae_ref[...]
+                          * swe_ref[...]).astype(o_ref.dtype)
 
         @pl.when(jnp.logical_not(act))
         def _inactive():
@@ -254,8 +254,8 @@ def ip2_fused_embed_pallas(
     patches: jnp.ndarray,     # (P_rows, K) dense pixel voltages in [0,1]
     w_q: jnp.ndarray,         # (K, M) DAC-quantized projection weights
     w8_embed: jnp.ndarray,    # (M, D) int8 embed codes (pad rows ZERO)
-    sw_embed: jnp.ndarray,    # (D,) float32 per-col embed scales
-    sa_rows: jnp.ndarray,     # (R,) float32 per-row code scales (the ADC LSB)
+    sw_embed: jnp.ndarray,    # (1, D) float32 per-col embed scales
+    sa_rows: jnp.ndarray,     # (R, 1) float32 per-row code scales (the ADC LSB)
     params: IP2KernelParams,
     n_banks: int,
     block_r: int = 8,
@@ -288,8 +288,8 @@ def ip2_fused_embed_pallas(
     (R,) = row_idx.shape
     (S,) = row_counts.shape
     rps = n_banks * block_r
-    assert K == K2 and M == M2 and sw_embed.shape == (D,) and R == S * rps
-    assert sa_rows.shape == (R,)
+    assert K == K2 and M == M2 and sw_embed.shape == (1, D) and R == S * rps
+    assert sa_rows.shape == (R, 1)
     assert M % block_m == 0 and K % block_k == 0 and D % 128 == 0, (
         f"pad shapes to blocks: {(K, M, D)} vs {(block_k, block_m, 128)}"
     )
@@ -301,14 +301,14 @@ def ip2_fused_embed_pallas(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=[
-            *(pl.BlockSpec((1, block_k), _row_map(r, rps, block_r))
+            *(pl.BlockSpec((None, 1, block_k), _row_map(r, rps, block_r))
               for r in range(block_r)),
             pl.BlockSpec((block_k, block_m), _w_map(block_r)),
             # embed weights/scales: one constant block, fetched once
             pl.BlockSpec((M, D), lambda s, i, j, k, idx, cnt: (0, 0)),
-            pl.BlockSpec((D,), lambda s, i, j, k, idx, cnt: (0,)),
+            pl.BlockSpec((1, D), lambda s, i, j, k, idx, cnt: (0, 0)),
             pl.BlockSpec(
-                (block_r,), lambda s, i, j, k, idx, cnt: (s * n_banks + i,)
+                (block_r, 1), lambda s, i, j, k, idx, cnt: (s * n_banks + i, 0)
             ),
         ],
         out_specs=pl.BlockSpec(
@@ -327,11 +327,11 @@ def ip2_fused_embed_pallas(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, D), jnp.float32),
-        compiler_params=COMPILER_PARAMS_CLS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")
         ),
         interpret=interpret,
     )(row_idx.astype(jnp.int32), row_counts.astype(jnp.int32),
-      *([patches] * block_r), w_q, w8_embed, sw_embed,
+      *([patches[:, None, :]] * block_r), w_q, w8_embed, sw_embed,
       sa_rows.astype(jnp.float32))

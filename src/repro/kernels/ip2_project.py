@@ -33,9 +33,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import adc as adc_mod
 
-# renamed across jax releases: CompilerParams (new) vs TPUCompilerParams (old)
-COMPILER_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 @dataclasses.dataclass(frozen=True)
 class IP2KernelParams:
@@ -113,7 +110,7 @@ def analog_epilogue_tile(acc: jnp.ndarray, b: jnp.ndarray, p: IP2KernelParams) -
     if p.adc_out_codes:
         return code
     scale, zero = adc_mod.readout_scale_zero(p.v_ref, b, spec)
-    return adc_mod.dequantize(code, scale, zero)
+    return adc_mod.dequantize(code, scale, zero, code_bits=spec.bits)
 
 
 def _ip2_kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, p: IP2KernelParams, k_steps: int):
@@ -138,7 +135,7 @@ def _ip2_kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, p: IP2KernelParams, k_st
 def ip2_project_pallas(
     patches: jnp.ndarray,      # (P, K) pixel voltages in [0,1]; K = padded N2
     w_q: jnp.ndarray,          # (K, M) DAC-quantized weights (pre-quantized)
-    bias: jnp.ndarray,         # (M,)
+    bias: jnp.ndarray,         # (1, M) — 2-D so Mosaic tiles it like the output
     params: IP2KernelParams,
     block_p: int = 128,
     block_m: int = 128,
@@ -148,7 +145,7 @@ def ip2_project_pallas(
     """Padded-shape kernel entry; use repro.kernels.ops.ip2_project."""
     P, K = patches.shape
     K2, M = w_q.shape
-    assert K == K2 and bias.shape == (M,)
+    assert K == K2 and bias.shape == (1, M)
     assert P % block_p == 0 and M % block_m == 0 and K % block_k == 0, (
         f"pad shapes to blocks: {(P, K, M)} vs {(block_p, block_k, block_m)}"
     )
@@ -161,12 +158,12 @@ def ip2_project_pallas(
         in_specs=[
             pl.BlockSpec((block_p, block_k), lambda i, j, k: (i, k)),
             pl.BlockSpec((block_k, block_m), lambda i, j, k: (k, j)),
-            pl.BlockSpec((block_m,), lambda i, j, k: (j,)),
+            pl.BlockSpec((1, block_m), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((block_p, block_m), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((P, M), params.out_dtype),
         scratch_shapes=[pltpu.VMEM((block_p, block_m), jnp.float32)],
-        compiler_params=COMPILER_PARAMS_CLS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

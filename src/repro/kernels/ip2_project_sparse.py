@@ -13,8 +13,10 @@ and power down".
 
 Grid = (active row banks, vector banks, K banks). One grid step processes
 ``block_r`` *arbitrary* (non-contiguous) dense rows: the patch operand is
-passed ``block_r`` times with single-row BlockSpecs whose index_maps each
-read their own slot of the prefetched row table (``idx[i*block_r + r]``),
+viewed ``(rows, 1, K)`` and passed ``block_r`` times with single-row
+BlockSpecs (squeezed row dim over a ``(1, block_k)`` tile, the shape the
+chip's tiling accepts) whose index_maps each read their own slot of the
+prefetched row table (``idx[i*block_r + r]``),
 and the kernel body stacks the gathered rows into one (block_r, block_k)
 tile for the MXU. Selection therefore stays patch-granular for any saccade
 pattern while the matmul and the grid amortize over a sublane-aligned row
@@ -40,7 +42,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ip2_project import (
-    COMPILER_PARAMS_CLS,
     IP2KernelParams,
     analog_epilogue_tile,
     pwm_quantize_tile,
@@ -80,7 +81,7 @@ def ip2_project_sparse_pallas(
     row_idx: jnp.ndarray,      # (R,) int32 dense row indices of active patches
     patches: jnp.ndarray,      # (P_rows, K) dense pixel voltages in [0,1]
     w_q: jnp.ndarray,          # (K, M) DAC-quantized weights (pre-quantized)
-    bias: jnp.ndarray,         # (M,)
+    bias: jnp.ndarray,         # (1, M)
     params: IP2KernelParams,
     block_r: int = 8,
     block_m: int = 128,
@@ -96,7 +97,7 @@ def ip2_project_sparse_pallas(
     p_rows, K = patches.shape
     K2, M = w_q.shape
     (R,) = row_idx.shape
-    assert K == K2 and bias.shape == (M,)
+    assert K == K2 and bias.shape == (1, M)
     assert R % block_r == 0 and M % block_m == 0 and K % block_k == 0, (
         f"pad shapes to blocks: {(R, K, M)} vs {(block_r, block_k, block_m)}"
     )
@@ -105,15 +106,17 @@ def ip2_project_sparse_pallas(
 
     def _row_map(r):
         # the gather: slot r of row bank i loads dense row idx[i*block_r + r]
-        return lambda i, j, k, idx: (idx[i * block_r + r], k)
+        return lambda i, j, k, idx: (idx[i * block_r + r], 0, k)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            *(pl.BlockSpec((1, block_k), _row_map(r)) for r in range(block_r)),
+            # patches are viewed (rows, 1, K): a squeezed row dim over a
+            # (1, block_k) tile, so each operand DMAs one dense row
+            *(pl.BlockSpec((None, 1, block_k), _row_map(r)) for r in range(block_r)),
             pl.BlockSpec((block_k, block_m), lambda i, j, k, idx: (k, j)),
-            pl.BlockSpec((block_m,), lambda i, j, k, idx: (j,)),
+            pl.BlockSpec((1, block_m), lambda i, j, k, idx: (0, j)),
         ],
         out_specs=pl.BlockSpec((block_r, block_m), lambda i, j, k, idx: (i, j)),
         scratch_shapes=[pltpu.VMEM((block_r, block_m), jnp.float32)],
@@ -125,8 +128,8 @@ def ip2_project_sparse_pallas(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, M), params.out_dtype),
-        compiler_params=COMPILER_PARAMS_CLS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(row_idx.astype(jnp.int32), *([patches] * block_r), w_q, bias)
+    )(row_idx.astype(jnp.int32), *([patches[:, None, :]] * block_r), w_q, bias)

@@ -174,7 +174,7 @@ def ip2_project(
     p_pad = _pad_to(flat.astype(jnp.float32), 0, block_p)
     k_in = _pad_to(p_pad, 1, block_k)
     w_pad = _pad_to(_pad_to(w_t.astype(jnp.float32), 0, block_k), 1, block_m)
-    b_pad = _pad_to(b, 0, block_m)
+    b_pad = _pad_to(b, 0, block_m)[None, :]
 
     params = kernel_params_from_spec(spec, adc, codes, readout)
     out = ip2_project_pallas(
@@ -401,12 +401,14 @@ def ip2_project_sparse(
     b = jnp.zeros((m,), jnp.float32) if bias is None else bias.astype(jnp.float32)
     k_in = _pad_to(flat_p, 1, block_k)
     w_pad = _pad_to(_pad_to(w_q.T.astype(jnp.float32), 0, block_k), 1, block_m)
-    b_pad = _pad_to(b, 0, block_m)
+    b_pad = _pad_to(b, 0, block_m)[None, :]
     params = kernel_params_from_spec(spec, adc, codes, readout)
 
     if row_counts is not None:
+        # a bank stays one sublane tile even when k < 8: the chip tiles
+        # the (bank, M) output block by 8 rows; pad rows are clamped
+        # duplicates, masked below
         br = 8 if block_r is None else block_r
-        br = max(1, min(br, k))
         table, counts, n_banks = _ragged_tables(indices, n_patches, row_counts, br)
         out = ip2_ragged_pallas(
             table, counts, k_in, w_pad, b_pad, params, n_banks=n_banks,
@@ -486,7 +488,7 @@ def ip2_fused_embed(
 
     flat_p = patches.reshape(-1, n2).astype(jnp.float32)
     batch = flat_p.shape[0] // n_patches
-    br = max(1, min(block_r, k))
+    br = block_r          # one sublane tile per bank, as in the ragged path
     table, counts, n_banks = _ragged_tables(indices, n_patches, row_counts, br)
 
     # roofline-picked default (benchmarks/bench_roofline.py): one vector-bank
@@ -501,11 +503,11 @@ def ip2_fused_embed(
     # codes (epilogue of an empty accumulator) and the zero rows annihilate
     # them exactly in the int32 sum — the bitwise-parity keystone.
     w8_pad = _pad_to(_pad_to(w8, 0, block_m, value=0), 1, 128, value=0)
-    sw_pad = _pad_to(s_w.astype(jnp.float32), 0, 128)
+    sw_pad = _pad_to(s_w.astype(jnp.float32), 0, 128)[None, :]
 
     # per-row activation scale = the ADC's single static LSB, materialized
     # as a buffer so the kernel epilogue multiplies in quant_matmul order
-    sa_rows = jnp.full((table.shape[0],), adc.lsb, jnp.float32)
+    sa_rows = jnp.full((table.shape[0], 1), adc.lsb, jnp.float32)
 
     params = kernel_params_from_spec(spec, adc, codes=True)
     out = ip2_fused_embed_pallas(
@@ -550,9 +552,9 @@ def quant_matmul_pre(
     s_flat = jnp.broadcast_to(jnp.asarray(s_a, jnp.float32), lead).reshape(-1)
 
     a_pad = _pad_to(_pad_to(flat, 0, block_p), 1, block_k)
-    sa_pad = _pad_to(s_flat, 0, block_p)
+    sa_pad = _pad_to(s_flat, 0, block_p)[:, None]
     w_pad = _pad_to(_pad_to(w8, 0, block_k), 1, block_m)
-    sw_pad = _pad_to(s_w.astype(jnp.float32), 0, block_m)
+    sw_pad = _pad_to(s_w.astype(jnp.float32), 0, block_m)[None, :]
 
     # thread the requested out_dtype into the kernel: the epilogue casts
     # from its f32 accumulator exactly once, so bf16 consumers don't pay a

@@ -71,7 +71,7 @@ def _kv_map(block_q):
 def _mask_map(block_q):
     def m(b, h, qb, cnt):
         act = (qb * block_q) < cnt[b]
-        return (jnp.where(act, b, 0), 0)
+        return (jnp.where(act, b, 0), 0, 0)
 
     return m
 
@@ -98,7 +98,7 @@ def _delta_attn_kernel(
             preferred_element_type=jnp.float32,
         )
         sc = sc / jnp.sqrt(jnp.asarray(dh, sc.dtype))
-        msk = m_ref[0] > 0.5
+        msk = m_ref[0] > 0.5   # (S_p,)
         sc = jnp.where(msk[None, :], sc, NEG_INF)
         probs = jax.nn.softmax(sc, axis=-1)
         o = jax.lax.dot_general(
@@ -150,7 +150,8 @@ def delta_attention_pallas(
     qt, kt, vt = prep(q), prep(k), prep(v)
     s_p, dh_p = qt.shape[2], qt.shape[3]
     # padded key rows are invalid: they mask to NEG_INF and mix nothing
-    mask_f = _pad_axis(key_mask.astype(jnp.float32), 1, block_q)
+    # (B, 1, S_p): a (1, S_p) block per slot spans the array's last two dims
+    mask_f = _pad_axis(key_mask.astype(jnp.float32), 1, block_q)[:, None, :]
 
     grid = (B, H, s_p // block_q)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -160,7 +161,7 @@ def delta_attention_pallas(
             pl.BlockSpec((1, 1, block_q, dh_p), _q_map(block_q)),
             pl.BlockSpec((1, 1, s_p, dh_p), _kv_map(block_q)),
             pl.BlockSpec((1, 1, s_p, dh_p), _kv_map(block_q)),
-            pl.BlockSpec((1, s_p), _mask_map(block_q)),
+            pl.BlockSpec((None, 1, s_p), _mask_map(block_q)),
         ],
         # output map is NOT clamped: every bank owns its own block
         out_specs=pl.BlockSpec(

@@ -180,8 +180,9 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, do_roofline: bool = Tru
     # Training cells auto-scale gradient-accumulation microbatches until the
     # step fits 16 GiB HBM; the escalation path is recorded.
     from repro.roofline.analysis import collective_bytes
+    from repro.roofline.peaks import V5E
 
-    hbm = 16 * 1024**3
+    hbm = V5E.hbm_bytes
     mb_trail = []
     if shape.is_train:
         dp_total = n_chips // plan.tp
@@ -222,10 +223,8 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, do_roofline: bool = Tru
     # fusion-aware HBM traffic floor: every argument byte is read once; train
     # additionally writes params/opt back. XLA:CPU "bytes accessed" is
     # fusion-blind and overestimates; this floor brackets reality from below.
-    from repro.launch.mesh import HBM_BW
-
     k = 3.0 if shape.is_train else 1.0
-    rec["t_memory_floor_s"] = k * ma.argument_size_in_bytes / HBM_BW
+    rec["t_memory_floor_s"] = k * ma.argument_size_in_bytes / V5E.hbm_bytes_per_s
     del compiled, lowered
 
     if not do_roofline:

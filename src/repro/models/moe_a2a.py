@@ -31,7 +31,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -147,6 +147,6 @@ def apply_moe_a2a(
         inner, mesh=mesh,
         in_specs=(w_specs, x_spec),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )({k_: p[k_] for k_ in w_specs}, x)
     return out, aux

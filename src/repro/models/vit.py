@@ -30,6 +30,7 @@ paper describes in §1/§2.1.3.
 from __future__ import annotations
 
 import dataclasses
+import math
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +46,7 @@ from repro.core.frontend import (
     init_frontend_params,
     select_compact,
 )
+from repro.core.projection import PatchSpec
 from repro.models.layers import DEFAULT_PLAN, apply_mlp, dense_init, init_mlp, rms_norm
 from repro.models.attention import init_attention
 from repro.configs.base import ModelConfig
@@ -86,6 +88,38 @@ class ViTConfig:
             d_ff=self.d_ff, vocab=0, head_dim=self.d_model // self.n_heads,
             mlp_kind="gelu", qkv_bias=True, remat=False,
         )
+
+
+def vit_config_from(model: ModelConfig, frontend_kw: dict | None = None,
+                    **vit_kw) -> ViTConfig:
+    """A :class:`ViTConfig` at a registered vision config's published
+    widths (``configs/ip2_vit.py``): square frames of
+    ``sqrt(n_image_tokens)`` patches of ``ip2_patch`` pixels a side,
+    ``ip2_vectors`` analog vectors per patch, and the trunk's depth and
+    widths. ``frontend_kw`` sets the other FrontendConfig fields (active
+    fraction, temporal gate) and ``vit_kw`` the other ViTConfig fields
+    (serving modes, ``n_classes``)."""
+    if model.vision_frontend != "ip2":
+        raise ValueError(f"{model.name} has no IP2 frontend")
+    side = math.isqrt(model.n_image_tokens)
+    if side * side != model.n_image_tokens:
+        raise ValueError(
+            f"{model.name}: {model.n_image_tokens} image tokens is not a "
+            f"square patch grid")
+    if model.head_dim * model.n_heads != model.d_model:
+        raise ValueError(
+            f"{model.name}: head_dim {model.head_dim} x {model.n_heads} "
+            f"heads != d_model {model.d_model}")
+    image = side * model.ip2_patch
+    fcfg = FrontendConfig(
+        image_h=image, image_w=image,
+        patch=PatchSpec(patch_h=model.ip2_patch, patch_w=model.ip2_patch,
+                        n_vectors=model.ip2_vectors),
+        **(frontend_kw or {}),
+    )
+    return ViTConfig(frontend=fcfg, n_layers=model.n_layers,
+                     d_model=model.d_model, n_heads=model.n_heads,
+                     d_ff=model.d_ff, **vit_kw)
 
 
 def init_vit(key, cfg: ViTConfig) -> dict:

@@ -1,8 +1,9 @@
 """Roofline extraction from compiled dry-run artifacts.
 
-Three terms per (arch, shape, mesh), in seconds (TPU v5e constants):
+Three terms per (arch, shape, mesh), in seconds, priced against the TPU
+v5e row of the device peaks table (:mod:`repro.roofline.peaks`):
 
-    compute    = HLO_FLOPs_per_chip / peak_FLOPs
+    compute    = float_FLOPs / bf16_peak + int8_OPs / int8_peak
     memory     = HLO_bytes_per_chip / HBM_bw
     collective = collective_bytes_per_chip / ICI_bw
 
@@ -23,7 +24,7 @@ from __future__ import annotations
 import dataclasses
 import re
 
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.roofline.peaks import V5E
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -91,18 +92,23 @@ class RooflineTerms:
     flops_per_chip: float
     bytes_per_chip: float
     coll_bytes_per_chip: float
+    # the part of flops_per_chip the MXU runs as int8 x int8 (w8a8 paths):
+    # priced at the int8 peak, the rest at the bf16 peak
+    int8_flops_per_chip: float = 0.0
 
     @property
     def t_compute(self) -> float:
-        return self.flops_per_chip / PEAK_FLOPS_BF16
+        float_flops = self.flops_per_chip - self.int8_flops_per_chip
+        return (float_flops / V5E.bf16_flops
+                + self.int8_flops_per_chip / V5E.int8_ops)
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_per_chip / HBM_BW
+        return self.bytes_per_chip / V5E.hbm_bytes_per_s
 
     @property
     def t_collective(self) -> float:
-        return self.coll_bytes_per_chip / ICI_BW
+        return self.coll_bytes_per_chip / V5E.ici_bytes_per_s
 
     @property
     def bottleneck(self) -> str:
@@ -131,6 +137,7 @@ class RooflineTerms:
             "flops_per_chip": self.flops_per_chip,
             "bytes_per_chip": self.bytes_per_chip,
             "coll_bytes_per_chip": self.coll_bytes_per_chip,
+            "int8_flops_per_chip": self.int8_flops_per_chip,
             "t_compute_s": self.t_compute,
             "t_memory_s": self.t_memory,
             "t_collective_s": self.t_collective,
@@ -192,7 +199,9 @@ def megakernel_cost(
     shed-row payload). ``d`` prices the fused embed stage (codes @ W8)
     on top; ``d=None`` is the ragged projection alone with ``out_bytes``
     per emitted element (1 for the int8 code wire). Same keys as
-    :func:`cost_point` so :class:`RooflineTerms` consumes either.
+    :func:`cost_point` so :class:`RooflineTerms` consumes either, plus
+    ``int8_flops``: the embed stage's share of ``flops``, which runs
+    int8 x int8 on the MXU.
     """
     k_pad = -(-n2 // block_k) * block_k
     m_pad = -(-m // block_m) * block_m
@@ -202,17 +211,20 @@ def megakernel_cost(
     total_banks = len(counts) * n_banks
 
     flops = active_banks * 2.0 * block_r * k_pad * m_pad
+    int8_flops = 0.0
     bytes_ = active_banks * block_r * k_pad * 4.0       # gathered patch rows
     bytes_ += active_banks * k_pad * m_pad * 4.0        # weight stream/bank
     if d is None:
         bytes_ += total_banks * block_r * m_pad * float(out_bytes)
     else:
         d_pad = -(-d // 128) * 128
-        flops += active_banks * 2.0 * block_r * m_pad * d_pad
+        int8_flops = active_banks * 2.0 * block_r * m_pad * d_pad
+        flops += int8_flops
         bytes_ += m_pad * d_pad * 1.0 + d_pad * 4.0     # embed w8 + scales
         bytes_ += total_banks * block_r * d_pad * 4.0   # f32 embed output
     return {
         "flops": flops,
+        "int8_flops": int8_flops,
         "bytes": bytes_,
         "coll_bytes": 0.0,
         "detail": {"active_banks": active_banks, "total_banks": total_banks},

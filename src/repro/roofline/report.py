@@ -63,12 +63,12 @@ def roofline_fraction(cell: dict, use_floor: bool = False) -> float | None:
     peak the step's *useful* math achieves if the step runs exactly at its
     roofline bound. use_floor swaps the fusion-blind XLA byte count for
     the fusion-aware argument-traffic floor."""
-    from repro.launch.mesh import PEAK_FLOPS_BF16
+    from repro.roofline.peaks import V5E
 
     rl = cell.get("roofline")
     if not rl:
         return None
-    t_model = rl["model_flops_per_chip"] / PEAK_FLOPS_BF16
+    t_model = rl["model_flops_per_chip"] / V5E.bf16_flops
     t_mem = cell.get("t_memory_floor_s", 0.0) if use_floor else rl["t_memory_s"]
     t_bound = max(rl["t_compute_s"], t_mem, rl["t_collective_s"])
     return t_model / t_bound if t_bound else None

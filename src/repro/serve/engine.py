@@ -107,7 +107,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.power import EnergyMeter, EventCounts, dense_backend_macs
@@ -602,12 +602,15 @@ class SaccadeEngine:
             # only shard_map when the slot axis actually survived
             if any(a is not None for a in spec):
                 self._slot_spec = spec
-                # per-slot parallel, params replicated — no collectives
+                # per-slot parallel, params replicated — no collectives.
+                # No varying-axes check: the Pallas kernels' outputs carry
+                # no such annotation, and nothing here reduces over slots
                 fn = shard_map(
                     fn, mesh=mesh,
                     in_specs=(P(), self._slot_spec, self._slot_spec,
                               self._slot_spec),
                     out_specs=(self._slot_spec, self._slot_spec),
+                    check_vma=False,
                 )
 
         def counted(params, frames, fed, state):
@@ -665,6 +668,16 @@ class SaccadeEngine:
     @property
     def n_traces(self) -> int:
         return self._n_traces
+
+    def compile_step(self):
+        """Ahead-of-time compile the batched step for this engine's
+        arguments and return the ``jax.stages.Compiled`` — its
+        ``as_text()`` is the program the device runs. Shares jit's trace
+        cache, so ``n_traces`` still counts one trace."""
+        self._flush_churn()
+        return self._step_fn.lower(
+            self.params, self._frames_dev, jnp.asarray(self._fed),
+            self._state).compile()
 
     @property
     def n_rollout_traces(self) -> int:

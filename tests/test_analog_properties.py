@@ -149,7 +149,8 @@ if HAVE_HYPOTHESIS:
                 float(v0), np.asarray(times_us, np.float64))
 
         @settings(max_examples=30, deadline=None)
-        @given(st.floats(0.01, 100.0, width=32))
+        # a width-32 strategy takes only float32-exact bounds
+        @given(st.floats(float(np.float32(0.01)), 100.0, width=32))
         def test_droop_factor_matches_trace(self, hold_us):
             check_droop_factor_matches_trace(float(hold_us))
 
@@ -235,9 +236,11 @@ def check_nl_clip_bounds(v: np.ndarray, spec) -> None:
     out = np.asarray(analog_nonlinearity(jnp.asarray(v), spec))
     lo = -spec.v_sat if spec.kind == "none" else 0.0
     assert out.min() >= lo - 1e-7 and out.max() <= spec.v_sat + 1e-7
-    # inside the rails the transfer is the identity
+    # inside the rails the transfer is the identity; XLA flushes float32
+    # subnormal inputs to zero, as it does on every backend
     inside = (v > lo) & (v < spec.v_sat)
-    np.testing.assert_allclose(out[inside], v[inside], rtol=1e-6)
+    flushed = np.where(np.abs(v) < np.finfo(np.float32).tiny, 0.0, v)
+    np.testing.assert_allclose(out[inside], flushed[inside], rtol=1e-6)
 
 
 def check_nl_grad_finite(v: np.ndarray, spec) -> None:

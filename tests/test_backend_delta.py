@@ -404,17 +404,28 @@ class TestEngineBackend:
     def test_static_stream_reaches_zero_backend_macs(self, served):
         cfg, params = served
         # the explore/baseline policy period-2 oscillates the gaze on some
-        # scenes; pick one whose selection converges (batch(0,4) image 0:
-        # fully cached from step 2 on)
+        # scenes, and which ones depends on the host's float rounding:
+        # every scene whose selection has converged must be served from
+        # the cache, and at least one of the four must converge
         imgs, _ = SceneStream(image=64).batch(0, 4)
         # empty slots must not block the whole-batch skip (act mask)
         eng = SaccadeEngine(cfg, params, capacity=4, temporal=True,
                             backend_delta=True)
-        eng.admit("a")
-        for t in range(10):
-            eng.step({"a": imgs[0]})
-        assert eng.backend_cached("a")
-        assert float(eng.events("a", "last").backend_macs) == 0.0
+        converged = 0
+        for i, img in enumerate(imgs):
+            sid = f"s{i}"
+            eng.admit(sid)
+            gazes = []
+            for t in range(10):
+                eng.step({sid: img})
+                gazes.append(np.sort(np.asarray(eng.gaze(sid))))
+            if all(np.array_equal(g, gazes[-1]) for g in gazes[-3:]):
+                converged += 1
+                assert eng.backend_cached(sid)
+                assert float(eng.events(sid, "last").backend_macs) == 0.0
+            eng.evict(sid)
+        assert converged >= 1
+        assert eng.n_traces == 1
 
     def test_churn_wipes_backend_cache_without_retrace(self, served):
         cfg, params = served

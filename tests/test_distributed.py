@@ -58,7 +58,8 @@ def test_sharded_train_step_matches_single_device():
         p_ref, _, m_ref = step_ref(params, opt_state, batch)
 
         # sharded
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2), ("data", "model"))
         plan = plan_for(cfg, mesh)
         params2 = M.init_params(key, cfg, plan)   # same shapes (tp padding no-op: tp=2 divides)
         opt2 = init_opt_state(params2, opt)
@@ -125,7 +126,8 @@ def test_pipeline_parallel_matches_sequential():
     res = run_with_devices("""
         import json, jax, jax.numpy as jnp
         from repro.distributed.pipeline import pipeline_forward, split_layers_to_stages
-        mesh = jax.make_mesh((4,), ("pod",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("pod",))
         L, D = 8, 16
         w = jax.random.normal(jax.random.PRNGKey(0), (L, D, D)) * 0.4
         def stage_fn(params, x):
@@ -158,7 +160,8 @@ def test_pipeline_fewer_microbatches_than_stages():
     res = run_with_devices("""
         import json, jax, jax.numpy as jnp
         from repro.distributed.pipeline import pipeline_forward, split_layers_to_stages
-        mesh = jax.make_mesh((4,), ("pod",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("pod",))
         L, D = 4, 8
         w = jax.random.normal(jax.random.PRNGKey(0), (L, D, D)) * 0.4
         def stage_fn(params, x):
@@ -184,7 +187,8 @@ def test_pipeline_bubble_nan_does_not_poison_output():
     res = run_with_devices("""
         import json, jax, jax.numpy as jnp
         from repro.distributed.pipeline import pipeline_forward, split_layers_to_stages
-        mesh = jax.make_mesh((4,), ("pod",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("pod",))
         L, D = 4, 8
         w = jax.random.normal(jax.random.PRNGKey(0), (L, D, D)) * 0.4
         def body(c, p):
@@ -386,7 +390,8 @@ def test_compressed_allreduce_and_error_feedback():
     res = run_with_devices("""
         import json, jax, jax.numpy as jnp
         from repro.optim.compression import make_compressed_allreduce
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         fn = make_compressed_allreduce(mesh, "data")
         g = jax.random.normal(jax.random.PRNGKey(2), (8, 256))
         err = {"g": jnp.zeros((8, 256))}

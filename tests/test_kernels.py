@@ -183,7 +183,7 @@ class TestSparseProjection:
         want = ref.ip2_project_sparse_ref(idx, patches, w, bias, params)
         for block_r in (1, 2, 3, 6):
             got = ip2_project_sparse_pallas(
-                idx, patches, w, bias, params,
+                idx, patches, w, bias[None, :], params,
                 block_r=block_r, block_m=128, block_k=256, interpret=True,
             )
             np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)

@@ -66,7 +66,8 @@ def test_moe_a2a_matches_reference_multihost():
         p = moe_mod.init_moe(jax.random.PRNGKey(0), cfg)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, cfg.d_model)) * 0.5
         ref, aux_ref = moe_mod.apply_moe(p, x, cfg)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         out, aux = jax.jit(lambda p_, x_: apply_moe_a2a(
             p_, x_, cfg, mesh, ("data",), "model"))(p, x)
         g = jax.grad(lambda p_: apply_moe_a2a(

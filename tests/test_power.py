@@ -164,8 +164,10 @@ if HAVE_HYPOTHESIS:
             ).total_w >= t0
             assert power_report(
                 dataclasses.replace(base, frame_hz=r * bump)).total_w > t0
+            # at least one vector more: int(16 * 1.05) is still 16
+            more = max(m + 1, int(m * bump))
             assert power_report(
-                dataclasses.replace(base, n_vectors=int(m * bump))).total_w > t0
+                dataclasses.replace(base, n_vectors=more)).total_w > t0
 
         @given(
             f=st.floats(0.05, 1.0),

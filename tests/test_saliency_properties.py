@@ -43,7 +43,10 @@ def check_exactly_k_and_tiebreak(scores: np.ndarray, k: int) -> None:
     n = scores.shape[-1]
     idx = np.asarray(topk_patch_indices(jnp.asarray(scores), k))
     assert idx.shape == (k,) and len(set(idx.tolist())) == k
-    oracle = np.argsort(-scores, kind="stable")[:k]
+    # XLA compares float32 subnormals as zero, and -0.0 ties +0.0
+    flushed = np.where(np.abs(scores) < np.finfo(np.float32).tiny,
+                       np.float32(0.0), scores)
+    oracle = np.argsort(-flushed, kind="stable")[:k]
     np.testing.assert_array_equal(idx, oracle)
     mask = np.asarray(mask_from_indices(jnp.asarray(idx), n))
     assert int(mask.sum()) == k
@@ -154,6 +157,8 @@ def _score_battery():
         np.asarray([1, 0, 1, 0, 1, 0, 1, 0], np.float32),   # two-value comb
         np.asarray([3, 3, 3, 1, 1, 1, 2, 2], np.float32),   # tied plateaus
         np.asarray([0.5] * 5 + [1.0], np.float32),     # unique max, tied rest
+        np.asarray([-0.0, 0.0, -0.0], np.float32),     # signed zeros tie
+        np.asarray([0.0, 1.2e-39, -1e-40], np.float32),  # subnormals tie 0
     ]
     rng = np.random.default_rng(1234)
     for n in (2, 5, 13, 24):

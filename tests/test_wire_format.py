@@ -237,6 +237,25 @@ class TestRoundTripDeterministic:
         np.testing.assert_array_equal(np.asarray(scale), np.asarray(s2))
         np.testing.assert_array_equal(np.asarray(zero), np.asarray(z2))
 
+    @pytest.mark.parametrize("traced_scale", [False, True])
+    def test_dequantize_rounds_product_then_sum(self, traced_scale):
+        """Every int8 code dequantizes to round(round(code * scale) +
+        zero), also in a program that fuses the multiply into the add,
+        where XLA:CPU would contract a plain affine into an FMA
+        (DESIGN.md §9)."""
+        codes = jnp.arange(-128, 128, dtype=jnp.int8)
+        zero = (np.random.default_rng(0).normal(size=256) * 0.1
+                ).astype(np.float32)
+        scale = np.float32(2.0 / 255.0)
+        want = (np.asarray(codes, np.float64) * scale).astype(np.float32) + zero
+        if traced_scale:
+            got = jax.jit(lambda s, z: adc_mod.dequantize(codes, s, z))(
+                jnp.float32(scale), jnp.asarray(zero))
+        else:
+            got = jax.jit(lambda z: adc_mod.dequantize(codes, scale, z))(
+                jnp.asarray(zero))
+        np.testing.assert_array_equal(np.asarray(got), want)
+
 
 if HAVE_HYPOTHESIS:
 
