@@ -255,6 +255,9 @@ def run(args, root: str, here: str = spec.HERE, t_start: float | None = None,
         "knee": mix.get("knee"),
         "ticks": len(rec.ticks), "frames": len(rec.done),
         "dropped": rec.dropped, "n_traces": n_traces,
+        "churn_deferred": sum(w is not None for _, _, w in rec.churn),
+        "churn_defer_ms_max": max([(a - w) * 1e3 for _, a, w in rec.churn
+                                   if w is not None], default=0.0),
         "setup_weights_s": t_weights, "setup_s": setup_s,
         "setup_parts_s": {n: t - p for (n, t), (_, p) in
                           zip(marks, [("", t_start)] + marks[:-1])},

@@ -7,13 +7,16 @@ first and one per camera, with ``step(frames, block=False)``, then
 fetches the logits with ``handle.result()`` and the gaze each camera is
 told to convert next (the served indices). A frame's latency runs from
 the moment it was due to the moment its logits are on the host. Churn
-(evict, then admit) is applied between ticks at its scheduled time.
+(evict, then admit) is applied between ticks at its scheduled time, or
+once the evicted cameras' last frames are served, where they are still
+queued then.
 """
 
 from __future__ import annotations
 
 import contextlib
 import heapq
+import math
 import time
 
 import jax
@@ -50,7 +53,11 @@ class Record:
         self.out = {s: [] for s in sample}   # sid -> [(n, gaze, logits)]
         # per tick, over the fed slots: recomputed patches, backend MACs
         self.n_stale, self.macs = [], []
+        # frames due in the window and never served
         self.dropped = 0
+        # per churn burst, in seconds of the schedule: (due, applied, when
+        # it first waited for a tick to serve its cameras' frames, or None)
+        self.churn = []
 
 
 def fetch_served(eng):
@@ -93,6 +100,17 @@ def make_frame(sched, pool, sid: int, n: int) -> np.ndarray:
     return scenes_mod.paint(pool[scene], box, colour)
 
 
+def window_frames(st, lo: float, hi: float, n: int = 0) -> int:
+    """How many of camera ``st``'s frames, from its ``n``-th on, the
+    schedule makes due in ``[lo, min(t_evict, hi))``."""
+    hi = min(st.t_evict, hi)
+    count = 0
+    while st.due(n) < hi:
+        count += st.due(n) >= lo
+        n += 1
+    return count
+
+
 def serve_open(eng, sched, pool, rec: Record, seconds: float,
                spans: Spans, drain_s: float, preroll: float = 0.0,
                on_tick=lambda now: None) -> float:
@@ -102,40 +120,51 @@ def serve_open(eng, sched, pool, rec: Record, seconds: float,
     ``seconds`` are measured, and those still waiting when it closes are
     served after it (for up to ``drain_s``). ``on_tick(now)`` is called
     between ticks with the time since the window opened. Returns the
-    window's start on the host clock."""
+    window's start on the host clock.
+
+    Every frame a camera sent before its eviction is served: a churn
+    burst at ``t`` applies, whole (its evictions, then its admits), at
+    the first pass between ticks where no frame of a camera it evicts is
+    still queued (all of them are due before ``t``). What is attempted is
+    what the schedule makes due in the window; what failed is the part of
+    it still unserved when the drain ends."""
     end = preroll + seconds
-    heap = []   # (due, sid, n)
+    heap = []   # (due, sid, n): each live camera's next frame to serve
+
+    def queue(sid, n):
+        # a camera sends no frame at or after its eviction
+        st = sched.streams[sid]
+        if st.due(n) < st.t_evict:
+            heapq.heappush(heap, (st.due(n), sid, n))
+
     for sid in sched.initial:
-        heapq.heappush(heap, (sched.streams[sid].due(0), sid, 0))
+        queue(sid, 0)
     churn = list(sched.churn)
-    evicted = set()
+    waited = None                # when the next burst first waited
     origin = clock()
     while True:
         now = clock() - origin
         on_tick(now - preroll)
         while churn and churn[0][0] <= now:
-            t_c, out, add = churn.pop(0)
+            t_c, out, add = churn[0]
+            if any(sid in out for _, sid, _ in heap):
+                waited = now if waited is None else waited
+                break
+            churn.pop(0)
             for sid in out:
                 eng.evict(sid)
-                evicted.add(sid)
             for sid in add:
                 eng.admit(sid)
-                heapq.heappush(heap, (sched.streams[sid].due(0), sid, 0))
-        # frames of evicted cameras that were due before their eviction
-        # and never served are dropped
-        while heap and heap[0][1] in evicted:
-            due, sid, n = heapq.heappop(heap)
-            if preroll <= due < min(sched.streams[sid].t_evict, end):
-                rec.dropped += 1
-        if now >= end + drain_s or not heap or (
-                now >= end and heap[0][0] >= end):
-            # frames due in the window that never got served
-            rec.dropped += sum(1 for due, sid, n in heap
-                               if preroll <= due < end
-                               and sid not in evicted)
+                queue(sid, 0)
+            rec.churn.append((t_c, now, waited))
+            waited = None
+        # a burst still to apply may admit cameras with frames due in
+        # the window
+        if now >= end + drain_s or not churn and (not heap or (
+                now >= end and heap[0][0] >= end)):
             break
-        if heap[0][0] > now:
-            nxt = heap[0][0]
+        nxt = heap[0][0] if heap else math.inf
+        if nxt > now:
             if churn:
                 nxt = min(nxt, churn[0][0])
             with spans("wait_frames"):
@@ -149,8 +178,6 @@ def serve_open(eng, sched, pool, rec: Record, seconds: float,
             frames, meta = {}, {}
             while heap and heap[0][0] <= now:
                 due, sid, n = heapq.heappop(heap)
-                if sid in evicted:
-                    continue
                 if sid in frames:          # one frame per camera per tick
                     heapq.heappush(heap, (due, sid, n))
                     break
@@ -159,14 +186,23 @@ def serve_open(eng, sched, pool, rec: Record, seconds: float,
                 frames[sid] = make_frame(sched, pool, sid, n)
                 # frames of the pre-roll are served but not measured
                 meta[sid] = (n, origin + due if due >= preroll else None)
-                nd = sched.streams[sid].due(n + 1)
-                if nd < sched.streams[sid].t_evict:
-                    heapq.heappush(heap, (nd, sid, n + 1))
+                queue(sid, n + 1)
         if now >= preroll:
             rec.gen_s.append(clock() - t_start)
         if frames:
             tick(eng, frames, rec, spans, t_start, meta,
                  timed=now >= preroll)
+    streams = sched.streams
+    attempted = sum(window_frames(st, preroll, end)
+                    for st in streams.values())
+    rec.dropped = attempted - len(rec.due)
+    unserved = (sum(window_frames(streams[sid], preroll, end, n)
+                    for _, sid, n in heap)
+                + sum(window_frames(streams[sid], preroll, end)
+                      for _, _, add in churn for sid in add))
+    if rec.dropped != unserved:
+        raise RuntimeError(f"chipbench: {attempted} frames due in the window, "
+                           f"{len(rec.due)} served, but {unserved} unserved")
     return origin + preroll
 
 

@@ -3,7 +3,8 @@
 They cover the trace reduction on a hand-built trace, the operation and
 byte counts against the program's own counters, discovery of a config,
 traffic mix, check and metric added as files, whole runs of a tiny cell
-with the device check skipped (kernels in interpret mode), and that the
+with the device check skipped (kernels in interpret mode), the open
+loop's churn and failure accounting on a simulated clock, and that the
 comparison fails the lower-precision control and a broken timed path.
 """
 
@@ -434,6 +435,151 @@ def test_open_traffic_gives_every_seed_the_same_work():
     events = seen[0][1]
     assert sum(events) == round(mix["intruder_rate_per_s"] * len(events) * 42)
     assert events[0] > 10 * max(events[-1], 1)        # Zipf: a busy head
+
+
+class SimClock:
+    """The host clock of a simulated run: each reading takes 10 us, and
+    a sleep passes as much time as it asks for."""
+
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        self.t += 1e-5
+        return self.t - 1e-5
+
+    def sleep(self, s):
+        self.t += s
+
+
+class SimEngine:
+    """The engine's slots and churn as the serving loop sees them, on a
+    simulated clock: a step takes one tick of ``tick_s`` (5% jitter),
+    and ``freeze_s`` more where the tick spans a churn burst."""
+
+    def __init__(self, clock, capacity, tick_s, freeze_s, bursts, seed):
+        self.clock, self.capacity = clock, capacity
+        self.tick_s, self.freeze_s, self.bursts = tick_s, freeze_s, bursts
+        self.rng = np.random.default_rng(seed)
+        self.slots = {}
+        self.pending = []        # churn ops not yet flushed
+        self.flushes = []        # the churn ops each step flushed
+        self.served = []         # (sid, frame number) per frame served
+
+    def admit(self, sid):
+        if sid in self.slots or len(self.slots) == self.capacity:
+            raise RuntimeError(f"admit {sid} to {sorted(self.slots)}")
+        self.slots[sid] = min(set(range(self.capacity))
+                              - set(self.slots.values()))
+        self.pending.append(("admit", sid))
+
+    def evict(self, sid):
+        del self.slots[sid]
+        self.pending.append(("evict", sid))
+
+    def slot_of(self, sid):
+        return self.slots[sid]
+
+    def step(self, frames, block=False):
+        assert set(frames) <= set(self.slots), "a frame of an evicted camera"
+        self.flushes.append(self.pending)
+        self.pending = []
+        self.served.extend(frames.values())
+        t0 = self.clock.t
+        dt = self.tick_s * self.rng.uniform(0.95, 1.05)
+        if any(t0 < t <= t0 + dt for t in self.bursts):
+            dt += self.freeze_s
+        self.clock.t += dt
+        return types.SimpleNamespace(
+            result=lambda: {s: np.zeros(4, np.float32) for s in frames})
+
+
+def _serve_simulated(monkeypatch, sched, capacity, tick_s, freeze_s, seed,
+                     preroll, seconds, drain_s=60.0):
+    """``serving.serve_open`` over ``sched`` with a ``SimEngine``; a
+    frame is served as its (camera, frame number)."""
+    from chipbench import serving
+
+    clock = SimClock()
+    eng = SimEngine(clock, capacity, tick_s, freeze_s,
+                    [t for t, _, _ in sched.churn], seed)
+    for sid in sched.initial:
+        eng.admit(sid)
+    eng.pending = []
+    monkeypatch.setattr(serving, "clock", clock)
+    monkeypatch.setattr(serving, "time", types.SimpleNamespace(
+        sleep=clock.sleep))
+    monkeypatch.setattr(serving, "make_frame",
+                        lambda sched, pool, sid, n: (sid, n))
+    monkeypatch.setattr(serving, "fetch_served", lambda eng: (
+        np.zeros((capacity, 1), np.int32), np.zeros(capacity),
+        np.zeros(capacity)))
+    rec = serving.Record(set())
+    t0 = serving.serve_open(eng, sched, None, rec, seconds,
+                            serving.Spans(False), drain_s, preroll)
+    assert t0 == pytest.approx(preroll)
+    return eng, rec
+
+
+def _assert_served_once(sched, eng, rec, preroll, end):
+    """Every frame the schedule makes due in the window is served once,
+    none failed, and each churn burst reaches the engine in one flush."""
+    due = sorted((sid, n) for sid, st in sched.streams.items()
+                 for n in range(int((end - st.t_admit) * st.rate) + 2)
+                 if preroll <= st.due(n) < min(st.t_evict, end))
+    served = sorted(f for f in eng.served
+                    if preroll <= sched.streams[f[0]].due(f[1]) < end)
+    assert served == due
+    assert rec.dropped == 0
+    assert len(rec.due) + rec.dropped == len(due) == len(rec.due)
+    flush_of = {op: i for i, ops in enumerate(eng.flushes + [eng.pending])
+                for op in ops}
+    for t, out, add in sched.churn:
+        ops = [("evict", s) for s in out] + [("admit", s) for s in add]
+        assert len({flush_of[op] for op in ops}) == 1, t
+
+
+@pytest.mark.parametrize("seed", [3300000011, 2**31 + 99, 2**40 + 5])
+@pytest.mark.parametrize("tick_ms,freeze_ms", [(5.0, 0.0), (8.6, 0.0),
+                                               (12.0, 0.0), (8.6, 120.0)])
+def test_open_loop_serves_every_frame_before_churn(monkeypatch, seed,
+                                                   tick_ms, freeze_ms):
+    """On the real surveil schedule, served on a simulated clock, every
+    frame the schedule makes due in the window is served exactly once,
+    also those of cameras that a churn burst evicts while their frames
+    wait for a tick (the longest waits after a freeze across a burst),
+    and each burst reaches the engine whole, in one churn flush."""
+    from chipbench import traffic
+
+    mix = spec.traffic("surveil")
+    preroll, seconds = mix["preroll_s"], 40.0
+    sched = traffic.build(mix, seed, preroll + seconds, 256, 256)
+    eng, rec = _serve_simulated(monkeypatch, sched, mix["streams"],
+                                tick_ms / 1e3, freeze_ms / 1e3, seed,
+                                preroll, seconds, mix["drain_s"])
+    _assert_served_once(sched, eng, rec, preroll, preroll + seconds)
+    if freeze_ms:
+        assert any(w is not None for _, _, w in rec.churn)
+
+
+def test_open_loop_camera_evicted_before_its_first_frame(monkeypatch):
+    """A camera churned in and out again before its first frame is due
+    sends nothing: nothing of it is served or counted, and the second
+    burst does not wait for it."""
+    from chipbench import traffic
+
+    st = traffic.Stream
+    sched = traffic.Schedule(
+        "open", {0: st(0, 10.0, False, 0, 0.0, 0.5, 0.01, []),
+                 1: st(1, 10.0, False, 1, 0.0, np.inf, 0.02, []),
+                 2: st(2, 10.0, False, 2, 0.5, 0.55, 0.09, []),
+                 3: st(3, 10.0, False, 3, 0.55, np.inf, 0.03, [])},
+        [0, 1], [(0.5, [0], [2]), (0.55, [2], [3])], 4, {})
+    eng, rec = _serve_simulated(monkeypatch, sched, 2, 5e-3, 0.0, 1,
+                                0.2, 1.0)
+    _assert_served_once(sched, eng, rec, 0.2, 1.2)
+    assert all(sid != 2 for sid, _ in eng.served)
+    assert [w for _, _, w in rec.churn] == [None, None]
 
 
 def test_exits_without_a_tpu(tmp_path):
