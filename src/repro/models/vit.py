@@ -481,74 +481,80 @@ def vit_forward_compact(
             params, rgb, cfg, indices, mask, project_fn, precomputed,
             cache, wire, k_cap, stale_cap,
         )
-    out = apply_frontend(
-        params["ip2"], rgb, cfg.frontend,
-        mask=mask, indices=indices, mode="compact", project_fn=project_fn,
-        precomputed=precomputed, cache=cache, wire=wire,
-        k_cap=k_cap, stale_cap=stale_cap,
-    )
-    new_cache = None
-    if cache is not None:
-        out, new_cache = out
-    cf: CompactFeatures = out
-    if sign_mode is not None:
-        if jnp.issubdtype(cf.features.dtype, jnp.floating):
-            raise ValueError(
-                "sign_mode degrades the int8 code wire (DESIGN.md §13); "
-                "the float wire has no codes to degrade — it is the STE "
-                "training view, not a served payload")
-        c_thresh, c_pos, c_neg = adc_mod.sign_code_points(
-            cfg.frontend.patch.summer.v_ref, cfg.frontend.adc)
-        sm = sign_mode[:, None, None]
-        cf = cf._replace(features=jnp.where(
-            sm,
-            jnp.where(cf.features >= c_thresh, c_pos, c_neg)
-               .astype(cf.features.dtype),
-            cf.features))
-        ev = cf.events
-        cf = cf._replace(events=ev._replace(
-            adc_conversions=jnp.where(sign_mode, 0.0, ev.adc_conversions),
-            sign_comparisons=jnp.where(
-                sign_mode, ev.adc_conversions, ev.sign_comparisons),
-        ))
-    new_bcache = None
-    backend_macs = None
-    if backend_cache is not None:
-        from repro.models import backend_delta  # lazy: it imports us back
+    # layer scopes (metadata only; DESIGN.md §15)
+    with jax.named_scope("frontend"):
+        out = apply_frontend(
+            params["ip2"], rgb, cfg.frontend,
+            mask=mask, indices=indices, mode="compact", project_fn=project_fn,
+            precomputed=precomputed, cache=cache, wire=wire,
+            k_cap=k_cap, stale_cap=stale_cap,
+        )
+        new_cache = None
+        if cache is not None:
+            out, new_cache = out
+        cf: CompactFeatures = out
+        if sign_mode is not None:
+            if jnp.issubdtype(cf.features.dtype, jnp.floating):
+                raise ValueError(
+                    "sign_mode degrades the int8 code wire (DESIGN.md §13); "
+                    "the float wire has no codes to degrade — it is the STE "
+                    "training view, not a served payload")
+            c_thresh, c_pos, c_neg = adc_mod.sign_code_points(
+                cfg.frontend.patch.summer.v_ref, cfg.frontend.adc)
+            sm = sign_mode[:, None, None]
+            cf = cf._replace(features=jnp.where(
+                sm,
+                jnp.where(cf.features >= c_thresh, c_pos, c_neg)
+                   .astype(cf.features.dtype),
+                cf.features))
+            ev = cf.events
+            cf = cf._replace(events=ev._replace(
+                adc_conversions=jnp.where(sign_mode, 0.0, ev.adc_conversions),
+                sign_comparisons=jnp.where(
+                    sign_mode, ev.adc_conversions, ev.sign_comparisons),
+            ))
+    with jax.named_scope("encoder"):
+        new_bcache = None
+        backend_macs = None
+        if backend_cache is not None:
+            from repro.models import backend_delta  # lazy: it imports us back
 
-        if backend_cache.feats.dtype != cf.features.dtype:
-            raise ValueError(
-                f"backend cache dtype {backend_cache.feats.dtype} does "
-                f"not match wire payload {cf.features.dtype}; build it "
-                f"with init_backend_cache(..., dtype=<wire dtype>)")
-        if backend_cache.feats.shape[-2:] != cf.features.shape[-2:]:
-            raise ValueError(
-                f"backend cache rows {backend_cache.feats.shape[-2:]} do "
-                f"not match the served wire {cf.features.shape[-2:]}")
-        eps = (jnp.zeros(cf.valid.shape[0], jnp.float32)
-               if backend_eps is None
-               else jnp.broadcast_to(
-                   jnp.asarray(backend_eps, jnp.float32),
-                   (cf.valid.shape[0],)))
+            if backend_cache.feats.dtype != cf.features.dtype:
+                raise ValueError(
+                    f"backend cache dtype {backend_cache.feats.dtype} does "
+                    f"not match wire payload {cf.features.dtype}; build it "
+                    f"with init_backend_cache(..., dtype=<wire dtype>)")
+            if backend_cache.feats.shape[-2:] != cf.features.shape[-2:]:
+                raise ValueError(
+                    f"backend cache rows {backend_cache.feats.shape[-2:]} do "
+                    f"not match the served wire {cf.features.shape[-2:]}")
+            eps = (jnp.zeros(cf.valid.shape[0], jnp.float32)
+                   if backend_eps is None
+                   else jnp.broadcast_to(
+                       jnp.asarray(backend_eps, jnp.float32),
+                       (cf.valid.shape[0],)))
 
-        def embed_fn(cf=cf):
+            def embed_fn(cf=cf):
+                # index-based positional embeddings: pos[idx], not pos over P
+                with jax.named_scope("embed"):
+                    return (_embed_tokens(params, cf, cfg)
+                            + params["pos"][cf.indices])
+
+            logits, received, new_bcache, backend_macs = \
+                backend_delta.delta_forward(params, cfg, cf, embed_fn,
+                                            backend_cache, eps,
+                                            act=backend_act)
+        else:
             # index-based positional embeddings: pos[idx], not pos over P
-            return _embed_tokens(params, cf, cfg) + params["pos"][cf.indices]
+            with jax.named_scope("embed"):
+                x = _embed_tokens(params, cf, cfg) + params["pos"][cf.indices]
+            logits, received = _encoder(params, x, cfg, cf.valid)
 
-        logits, received, new_bcache, backend_macs = \
-            backend_delta.delta_forward(params, cfg, cf, embed_fn,
-                                        backend_cache, eps,
-                                        act=backend_act)
-    else:
-        # index-based positional embeddings: pos[idx], not pos over P
-        x = _embed_tokens(params, cf, cfg) + params["pos"][cf.indices]
-        logits, received = _encoder(params, x, cfg, cf.valid)
-
-    received = jnp.where(cf.valid, received, 0.0)
-    b = jnp.arange(received.shape[0])[:, None]
-    saliency = jnp.zeros(
-        (received.shape[0], cfg.frontend.n_patches), jnp.float32
-    ).at[b, cf.indices].max(received)
+        received = jnp.where(cf.valid, received, 0.0)
+        b = jnp.arange(received.shape[0])[:, None]
+        saliency = jnp.zeros(
+            (received.shape[0], cfg.frontend.n_patches), jnp.float32
+        ).at[b, cf.indices].max(received)
     events = cf.events
     if backend_macs is not None:
         # the ledger prices the delta accelerator's EXECUTED MACs (§14);

@@ -114,6 +114,7 @@ from repro.core.power import EnergyMeter, EventCounts, dense_backend_macs
 from repro.core.temporal import FeatureCache, init_feature_cache
 from repro.models import backend_delta as bdel
 from repro.serve import governor as gov_mod
+from repro.serve import telemetry
 from repro.serve.serve_step import make_rollout, saccade_scores
 
 
@@ -127,21 +128,26 @@ class StepHandle:
     actually wants the numbers. The handle stays valid across later
     engine calls — step outputs are fresh buffers, never donated — but
     holding many unfetched handles pins their logits in device memory;
-    fetch (or drop) them within a tick or two.
+    fetch (or drop) them within a tick or two. The fetch is the
+    ``engine.result`` span of the step's tick (``tick``).
     """
 
-    __slots__ = ("_logits", "_slots", "_out")
+    __slots__ = ("_logits", "_slots", "_out", "tick")
 
-    def __init__(self, logits, slots: dict):
+    def __init__(self, logits, slots: dict, tick: int | None = None):
         self._logits = logits
         self._slots = slots
         self._out = None
+        self.tick = tick
 
     def result(self) -> dict[Hashable, np.ndarray]:
         """Block until the logits are on the host; stream id -> (n_classes,)
         logits for exactly the fed streams. Idempotent."""
         if self._out is None:
-            arr = None if self._logits is None else np.asarray(self._logits)
+            arr = None
+            if self._logits is not None:
+                with telemetry.REGISTRY.span(telemetry.RESULT, self.tick):
+                    arr = np.asarray(self._logits)
             self._out = {sid: arr[s] for sid, s in self._slots.items()}
             self._logits = None          # drop the device reference
         return self._out
@@ -318,36 +324,42 @@ def make_engine_step(cfg, explore: float = 0.1, ema_decay: float = 0.0,
         # un-fed slots are a data-only hold (DESIGN.md §12): every row
         # below passes through unchanged, exactly like an inactive slot
         act = state.active & fed
-        # optics/mosaic/CDS once; forwarded to the compact forward below
-        patches, weights = fe.sensor_patches(params["ip2"], frames, fcfg)
-        boot = sal.topk_patch_indices(sal.patch_energy(patches), k)
-        fresh = state.frame_age == 0
-        indices = jnp.where(fresh[:, None], boot, state.indices)
+        # layer scopes (metadata only): a device trace attributes each op
+        # to its layer through telemetry's scope map (DESIGN.md §15)
+        with jax.named_scope("sensor"):
+            # optics/mosaic/CDS once; forwarded to the compact forward below
+            patches, weights = fe.sensor_patches(params["ip2"], frames, fcfg)
+            boot = sal.topk_patch_indices(sal.patch_energy(patches), k)
+            fresh = state.frame_age == 0
+            indices = jnp.where(fresh[:, None], boot, state.indices)
 
-        cache = None
-        if temporal:
-            cache = state.cache._replace(
-                valid=state.cache.valid & ~fresh[:, None]
-            )
-        bcache = eps = None
-        if backend:
-            # belt to the admit wipe, like the temporal cache above: a
-            # fresh slot must never reuse its predecessor's activations
-            bcache = state.bcache._replace(
-                valid=state.bcache.valid & ~fresh
-            )
+        with jax.named_scope("frontend"):
+            cache = None
+            if temporal:
+                cache = state.cache._replace(
+                    valid=state.cache.valid & ~fresh[:, None]
+                )
+            bcache = eps = None
+            if backend:
+                # belt to the admit wipe, like the temporal cache above: a
+                # fresh slot must never reuse its predecessor's activations
+                bcache = state.bcache._replace(
+                    valid=state.bcache.valid & ~fresh
+                )
+                if governor is not None:
+                    eps = state.controls.eps
+            k_cap = stale_cap = sign_mode = None
             if governor is not None:
-                eps = state.controls.eps
-        k_cap = stale_cap = sign_mode = None
-        if governor is not None:
-            k_cap = gov_mod.tier_k_eff(governor, state.controls.tier, k)
-            stale_cap = state.controls.j_cap
-            if governor.sign_tier:
-                # ADC-less tier (DESIGN.md §13): a (S,) bool DATA knob —
-                # flagged slots serve the 1-bit sign view of the code
-                # wire and re-ledger conversions as sign comparisons;
-                # the cache keeps full-precision codes for recovery
-                sign_mode = gov_mod.tier_is_sign(governor, state.controls.tier)
+                k_cap = gov_mod.tier_k_eff(governor, state.controls.tier, k)
+                stale_cap = state.controls.j_cap
+                if governor.sign_tier:
+                    # ADC-less tier (DESIGN.md §13): a (S,) bool DATA knob —
+                    # flagged slots serve the 1-bit sign view of the code
+                    # wire and re-ledger conversions as sign comparisons;
+                    # the cache keeps full-precision codes for recovery
+                    sign_mode = gov_mod.tier_is_sign(governor,
+                                                     state.controls.tier)
+        # scoped inside: frontend, encoder (embed)
         logits, aux = vit_forward_compact(
             params, frames, cfg, indices=indices,
             project_fn=project_fn, precomputed=(patches, weights),
@@ -355,51 +367,55 @@ def make_engine_step(cfg, explore: float = 0.1, ema_decay: float = 0.0,
             sign_mode=sign_mode, backend_cache=bcache, backend_eps=eps,
             backend_act=act if backend else None,
         )
-        scores = saccade_scores(aux, explore)
-        ema = jnp.where(
-            fresh[:, None], scores,
-            ema_decay * state.ema + (1.0 - ema_decay) * scores,
-        )
-        next_idx = sal.topk_patch_indices(ema, k)
-
-        # energy meters: only served slots spend events (held streams
-        # accrue zero — they converted nothing this tick). The cumulative
-        # meter is a RUNNING MEAN (Welford step over the frames served
-        # since admit): per-frame magnitude, so long-lived streams never
-        # freeze a float32 accumulator (see StreamState)
-        ev_last = EventCounts(*(
-            jnp.where(act, e, o)
-            for e, o in zip(aux["events"], state.events_last)
-        ))
-        n_served = (state.frame_age + 1).astype(jnp.float32)     # incl. this
-        ev_mean = EventCounts(*(
-            jnp.where(act, m + (e - m) / n_served, m)
-            for m, e in zip(state.events_mean, ev_last)
-        ))
-        controls = None
-        if governor is not None:
-            controls = gov_mod.control_update(
-                governor, state.controls,
-                EventCounts(*(e * act.astype(jnp.float32)
-                              for e in aux["events"])),
-                act, meter, frame_hz,
-                n_pixels, fcfg.patch.pixels_per_patch, fcfg.patch.n_vectors,
-                j_max, k, backend_mw=backend_mw,
+        with jax.named_scope("policy"):
+            scores = saccade_scores(aux, explore)
+            ema = jnp.where(
+                fresh[:, None], scores,
+                ema_decay * state.ema + (1.0 - ema_decay) * scores,
             )
-        new_state = StreamState(
-            indices=jnp.where(act[:, None], next_idx, state.indices),
-            ema=jnp.where(act[:, None], ema, state.ema),
-            frame_age=jnp.where(act, state.frame_age + 1, state.frame_age),
-            active=state.active,
-            cache=(_freeze_rows(act, aux["cache"], state.cache)
-                   if temporal else None),
-            events_last=ev_last,
-            events_mean=ev_mean,
-            controls=controls,
-            bcache=(_freeze_rows(act, aux["backend_cache"], state.bcache)
-                    if backend else None),
-        )
-        logits = jnp.where(act[:, None], logits, 0.0)
+            next_idx = sal.topk_patch_indices(ema, k)
+
+        with jax.named_scope("meters"):
+            # energy meters: only served slots spend events (held streams
+            # accrue zero — they converted nothing this tick). The
+            # cumulative meter is a RUNNING MEAN (Welford step over the
+            # frames served since admit): per-frame magnitude, so
+            # long-lived streams never freeze a float32 accumulator (see
+            # StreamState)
+            ev_last = EventCounts(*(
+                jnp.where(act, e, o)
+                for e, o in zip(aux["events"], state.events_last)
+            ))
+            n_served = (state.frame_age + 1).astype(jnp.float32)  # incl. this
+            ev_mean = EventCounts(*(
+                jnp.where(act, m + (e - m) / n_served, m)
+                for m, e in zip(state.events_mean, ev_last)
+            ))
+            controls = None
+            if governor is not None:
+                controls = gov_mod.control_update(
+                    governor, state.controls,
+                    EventCounts(*(e * act.astype(jnp.float32)
+                                  for e in aux["events"])),
+                    act, meter, frame_hz,
+                    n_pixels, fcfg.patch.pixels_per_patch,
+                    fcfg.patch.n_vectors, j_max, k, backend_mw=backend_mw,
+                )
+            new_state = StreamState(
+                indices=jnp.where(act[:, None], next_idx, state.indices),
+                ema=jnp.where(act[:, None], ema, state.ema),
+                frame_age=jnp.where(act, state.frame_age + 1,
+                                    state.frame_age),
+                active=state.active,
+                cache=(_freeze_rows(act, aux["cache"], state.cache)
+                       if temporal else None),
+                events_last=ev_last,
+                events_mean=ev_mean,
+                controls=controls,
+                bcache=(_freeze_rows(act, aux["backend_cache"], state.bcache)
+                        if backend else None),
+            )
+            logits = jnp.where(act[:, None], logits, 0.0)
         return logits, new_state
 
     return step
@@ -581,6 +597,9 @@ class SaccadeEngine:
             np.float32)
         self._stage_slots = np.zeros((capacity,), np.int32)
         self._fed = np.zeros((capacity,), bool)
+        # H2D bytes of one fed row: its frame plus its slot id
+        self._upload_row_bytes = (self._stage[0].nbytes
+                                  + self._stage_slots.itemsize)
         # rollout staging, cached per distinct T (matching the one-trace-
         # per-T compile contract). Un-fed rows keep stale bytes from the
         # previous rollout of the same T — safe for the same reason the
@@ -673,11 +692,14 @@ class SaccadeEngine:
         """Ahead-of-time compile the batched step for this engine's
         arguments and return the ``jax.stages.Compiled`` — its
         ``as_text()`` is the program the device runs. Shares jit's trace
-        cache, so ``n_traces`` still counts one trace."""
+        cache, so ``n_traces`` still counts one trace. Records the
+        program's layer-scope map in :mod:`repro.serve.telemetry`."""
         self._flush_churn()
-        return self._step_fn.lower(
+        compiled = self._step_fn.lower(
             self.params, self._frames_dev, jnp.asarray(self._fed),
             self._state).compile()
+        telemetry.REGISTRY.record_scopes(compiled.as_text())
+        return compiled
 
     @property
     def n_rollout_traces(self) -> int:
@@ -750,25 +772,28 @@ class SaccadeEngine:
 
     def _flush_churn(self) -> None:
         """Apply every pending admit/evict row-write (plus the governed
-        budget re-split, DESIGN.md §10/§12) in ONE jitted call."""
+        budget re-split, DESIGN.md §10/§12) in ONE jitted call: the
+        ``engine.churn_flush`` span, counting the rows it applies."""
         dirty_budget = self.governor is not None and self._budgets_dirty
         if not self._pending and not dirty_budget:
             return
-        admit_hit = np.zeros((self.capacity,), bool)
-        evict_hit = np.zeros((self.capacity,), bool)
-        for slot, op in self._pending.items():
-            (admit_hit if op == "admit" else evict_hit)[slot] = True
-        args = ()
-        if self.governor is not None:
-            w = np.zeros((self.capacity,), np.float64)
-            for slot, sid in enumerate(self._slots):
-                if sid is not None:
-                    w[slot] = self._priority[sid]
-            args = (jnp.asarray(gov_mod.allocate_budgets(
-                self.governor, w, total_mw=self._budget_mw)),)
-        self._state = self._churn_fn(
-            self._state, jnp.asarray(admit_hit), jnp.asarray(evict_hit),
-            *args)
+        with telemetry.REGISTRY.span(telemetry.CHURN_FLUSH,
+                                     count=len(self._pending)):
+            admit_hit = np.zeros((self.capacity,), bool)
+            evict_hit = np.zeros((self.capacity,), bool)
+            for slot, op in self._pending.items():
+                (admit_hit if op == "admit" else evict_hit)[slot] = True
+            args = ()
+            if self.governor is not None:
+                w = np.zeros((self.capacity,), np.float64)
+                for slot, sid in enumerate(self._slots):
+                    if sid is not None:
+                        w[slot] = self._priority[sid]
+                args = (jnp.asarray(gov_mod.allocate_budgets(
+                    self.governor, w, total_mw=self._budget_mw)),)
+            self._state = self._churn_fn(
+                self._state, jnp.asarray(admit_hit), jnp.asarray(evict_hit),
+                *args)
         self._pending.clear()
         self._budgets_dirty = False
 
@@ -813,6 +838,11 @@ class SaccadeEngine:
         rows are one compact H2D copy scattered into the persistent
         donated device frame buffer — never a full-capacity upload.
 
+        A call that dispatches is one tick of :mod:`repro.serve.telemetry`:
+        an ``engine.step`` span holding ``engine.stage``,
+        ``engine.churn_flush`` (when churn is pending), ``engine.upload``
+        and ``engine.dispatch``; the handle's fetch is ``engine.result``.
+
         With ``block=True`` (default) returns stream id -> (n_classes,)
         logits for exactly the fed streams. With ``block=False`` the
         call returns as soon as the step is DISPATCHED: you get a
@@ -825,15 +855,23 @@ class SaccadeEngine:
         if not frames:
             # nothing fed: all slots hold, no device dispatch
             return {} if block else StepHandle(None, {})
-        fed, slots_by_sid = self._stage_tick(frames)
-        self._flush_churn()
-        f = len(slots_by_sid)
-        self._frames_dev = self._scatter_fn(
-            self._frames_dev, jnp.asarray(self._stage[:f]),
-            jnp.asarray(self._stage_slots[:f]))
-        logits, self._state = self._step_fn(
-            self.params, self._frames_dev, jnp.asarray(fed), self._state)
-        handle = StepHandle(logits, slots_by_sid)
+        tel = telemetry.REGISTRY
+        tick = tel.new_tick()
+        f = len(frames)
+        with tel.span(telemetry.STEP, tick, count=1):
+            with tel.span(telemetry.STAGE, count=f):
+                fed, slots_by_sid = self._stage_tick(frames)
+            self._flush_churn()
+            with tel.span(telemetry.UPLOAD, count=f * self._upload_row_bytes):
+                rows = jnp.asarray(self._stage[:f])
+                slots = jnp.asarray(self._stage_slots[:f])
+            with tel.span(telemetry.DISPATCH):
+                self._frames_dev = self._scatter_fn(
+                    self._frames_dev, rows, slots)
+                logits, self._state = self._step_fn(
+                    self.params, self._frames_dev, jnp.asarray(fed),
+                    self._state)
+        handle = StepHandle(logits, slots_by_sid, tick)
         return handle.result() if block else handle
 
     def step_rollout(self, frames_by_tick, block: bool = True
