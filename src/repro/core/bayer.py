@@ -17,26 +17,33 @@ from __future__ import annotations
 import math
 
 import jax
-
 import jax.numpy as jnp
+from jax import lax
 
 # RGGB unit cell: channel index at (row%2, col%2)
-_BAYER_RGGB = ((0, 1), (1, 2))  # R G / G B
+RGGB = ((0, 1), (1, 2))  # R G / G B
 
 
 def bayer_channel_map(h: int, w: int) -> jnp.ndarray:
     """(H, W) int32 array of the color-channel index of each pixel site."""
     rows = jnp.arange(h)[:, None] % 2
     cols = jnp.arange(w)[None, :] % 2
-    cell = jnp.asarray(_BAYER_RGGB, dtype=jnp.int32)
+    cell = jnp.asarray(RGGB, dtype=jnp.int32)
     return cell[rows, cols]
 
 
 def mosaic(rgb: jnp.ndarray) -> jnp.ndarray:
-    """(..., H, W, 3) RGB -> (..., H, W) raw Bayer frame."""
+    """(..., H, W, 3) RGB -> (..., H, W) raw Bayer frame: each site keeps
+    its own colour's channel, chosen by row and column parity. A masked
+    sum over C adds exact zeros, so it is the selection bit for bit."""
     h, w = rgb.shape[-3], rgb.shape[-2]
-    onehot = jax.nn.one_hot(bayer_channel_map(h, w), 3, dtype=rgb.dtype)
-    return jnp.einsum("...hwc,hwc->...hw", rgb, onehot)
+    row = jnp.arange(h)[:, None, None] % 2
+    col = jnp.arange(w)[None, :, None] % 2
+    (c00, c01), (c10, c11) = RGGB
+    site = jnp.where(row == 0, jnp.where(col == 0, c00, c01),
+                     jnp.where(col == 0, c10, c11))          # (H, W, 1)
+    keep = site == jnp.arange(rgb.shape[-1])
+    return jnp.sum(jnp.where(keep, rgb, 0.0), axis=-1)
 
 
 def strike_columns(a_rgb: jnp.ndarray, patch_h: int, patch_w: int) -> jnp.ndarray:
@@ -70,21 +77,37 @@ def gaussian_kernel_1d(cutoff_nyquist: float, radius: int | None = None) -> jnp.
     return k / jnp.sum(k)
 
 
-def antialias(frame: jnp.ndarray, cutoff_nyquist: float = 0.5) -> jnp.ndarray:
-    """Separable Gaussian AA filter on (..., H, W) (reflect padding)."""
-    k = gaussian_kernel_1d(cutoff_nyquist)
-    r = (k.shape[0] - 1) // 2
+def aa_taps(cutoff_nyquist: float) -> tuple[float, ...]:
+    """The AA filter's taps as static float32 constants of a program."""
+    with jax.ensure_compile_time_eval():
+        return tuple(float(t) for t in gaussian_kernel_1d(cutoff_nyquist))
 
-    def conv_last(x):
-        xp = jnp.concatenate(
-            [x[..., 1 : r + 1][..., ::-1], x, x[..., -r - 1 : -1][..., ::-1]], axis=-1
-        )
-        windows = jnp.stack([xp[..., i : i + x.shape[-1]] for i in range(2 * r + 1)], axis=-1)
-        return jnp.einsum("...k,k->...", windows, k)
 
-    out = conv_last(frame)                     # along W
-    out = conv_last(out.swapaxes(-1, -2)).swapaxes(-1, -2)  # along H
+def _blur_axis(x: jnp.ndarray, taps: tuple[float, ...], axis: int) -> jnp.ndarray:
+    """One pass of the separable filter along ``axis``: reflect padding,
+    then each tap as a static shifted slice, summed in tap order."""
+    r = (len(taps) - 1) // 2
+    n = x.shape[axis]
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (r, r)
+    xp = jnp.pad(x, pad, mode="reflect")
+    out = lax.slice_in_dim(xp, 0, n, axis=axis) * taps[0]
+    for i in range(1, 2 * r + 1):
+        out = out + lax.slice_in_dim(xp, i, i + n, axis=axis) * taps[i]
     return out
+
+
+def antialias(frame: jnp.ndarray, cutoff_nyquist: float = 0.5, *,
+              channels_last: bool = False) -> jnp.ndarray:
+    """Separable Gaussian AA filter on (..., H, W), or on each channel of
+    (..., H, W, C) with ``channels_last`` (reflect padding).
+
+    Elementwise float32 products and sums with the taps as static
+    constants: no contraction, no transpose, no channel split."""
+    taps = aa_taps(cutoff_nyquist)
+    w_axis = frame.ndim - (2 if channels_last else 1)
+    out = _blur_axis(frame, taps, w_axis)            # along W
+    return _blur_axis(out, taps, w_axis - 1)         # along H
 
 
 def downsample2(frame: jnp.ndarray) -> jnp.ndarray:

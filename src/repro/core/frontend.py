@@ -185,17 +185,20 @@ def sensor_patches(
     shared verbatim by the dense and compact dataflows.
     """
     p = cfg.patch
-    if cfg.aa_cutoff is not None:
-        rgb = jnp.stack(
-            [bayer_mod.antialias(rgb[..., c], cfg.aa_cutoff) for c in range(3)], axis=-1
-        )
-
     if cfg.analog or cfg.bayer:
-        frame = bayer_mod.mosaic(rgb)                                # (..., H, W)
+        if cfg.aa_cutoff is None:
+            frame = bayer_mod.mosaic(rgb)                            # (..., H, W)
+        else:
+            from repro.kernels import ops  # lazy: keep the core import-light
+
+            # optics and mosaic in one kernel pass over the frame
+            frame = ops.bayer_frame(rgb, cfg.aa_cutoff)
         patches = proj_mod.extract_patches(frame, p.patch_h, p.patch_w)
         weights = bayer_mod.strike_columns(params["a_rgb"], p.patch_h, p.patch_w)
     else:
         # float simulation path: vectorized RGB patches
+        if cfg.aa_cutoff is not None:
+            rgb = bayer_mod.antialias(rgb, cfg.aa_cutoff, channels_last=True)
         per_c = [
             proj_mod.extract_patches(rgb[..., c], p.patch_h, p.patch_w) for c in range(3)
         ]

@@ -30,9 +30,11 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import bayer as bayer_mod
 from repro.core import projection as proj_mod
 from repro.core import pwm as pwm_mod
 from repro.kernels import ref
+from repro.kernels.bayer_sensor import bayer_frame_pallas
 from repro.kernels.ip2_megakernel import (
     ip2_fused_embed_pallas,
     ip2_ragged_pallas,
@@ -138,6 +140,21 @@ def kernel_params_from_spec(
         adc_enable=adc is not None,
         adc_out_codes=codes,
     )
+
+
+def bayer_frame(
+    rgb: jnp.ndarray, cutoff_nyquist: float, interpret: bool | None = None
+) -> jnp.ndarray:
+    """The Bayer sensor's optics and mosaic in one kernel pass:
+    ``(..., H, W, 3)`` RGB -> ``(..., H, W)`` float32 raw frame, equal to
+    ``bayer.mosaic(bayer.antialias(rgb, cutoff_nyquist,
+    channels_last=True))`` (:mod:`repro.kernels.bayer_sensor`)."""
+    lead, (h, w, c) = rgb.shape[:-3], rgb.shape[-3:]
+    flat = rgb.astype(jnp.float32).reshape((-1, h, w, c))
+    out = bayer_frame_pallas(flat, bayer_mod.aa_taps(cutoff_nyquist),
+                             bayer_mod.RGGB,
+                             interpret=_auto_interpret(interpret))
+    return out.reshape(lead + (h, w))
 
 
 def ip2_project(

@@ -5,7 +5,15 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro.core import adc as adc_mod
+from repro.core import bayer as bayer_mod
 from repro.kernels.ip2_project import IP2KernelParams
+
+
+def bayer_frame_ref(rgb: jnp.ndarray, cutoff_nyquist: float) -> jnp.ndarray:
+    """Oracle for bayer_frame_pallas: the AA filter on each channel in
+    place, then the RGGB mosaic."""
+    return bayer_mod.mosaic(
+        bayer_mod.antialias(rgb, cutoff_nyquist, channels_last=True))
 
 
 def ip2_project_ref(

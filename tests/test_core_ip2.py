@@ -142,6 +142,69 @@ class TestBayer:
         assert hf(c.antialias(x, 0.25)) < hf(c.antialias(x, 0.5)) < hf(x)
 
 
+def _onehot_mosaic(rgb):
+    """The one-hot contraction the mosaic was once written as."""
+    h, w = rgb.shape[-3], rgb.shape[-2]
+    onehot = jax.nn.one_hot(c.bayer_channel_map(h, w), 3, dtype=rgb.dtype)
+    return jnp.einsum("...hwc,hwc->...hw", rgb, onehot)
+
+
+def _oracle_sensor_patches(rgb, cutoff, patch):
+    """The sensor as once written, kept as the oracle: a tap-window stack
+    contracted by einsum, a transpose for the H pass, the three channels
+    restacked, the one-hot mosaic, then extract_patches."""
+    k = c.bayer.gaussian_kernel_1d(cutoff)
+    r = (k.shape[0] - 1) // 2
+
+    def conv_last(x):
+        xp = jnp.concatenate(
+            [x[..., 1 : r + 1][..., ::-1], x, x[..., -r - 1 : -1][..., ::-1]],
+            axis=-1)
+        windows = jnp.stack([xp[..., i : i + x.shape[-1]]
+                             for i in range(2 * r + 1)], axis=-1)
+        return jnp.einsum("...k,k->...", windows, k)
+
+    def old_antialias(x):
+        out = conv_last(x)
+        return conv_last(out.swapaxes(-1, -2)).swapaxes(-1, -2)
+
+    blurred = jnp.stack([old_antialias(rgb[..., ch]) for ch in range(3)],
+                        axis=-1)
+    return c.extract_patches(_onehot_mosaic(blurred), patch, patch)
+
+
+class TestSensorOracle:
+    @pytest.mark.parametrize("case,cutoff,hw", [
+        ("patches", 0.5, (64, 64)),
+        ("patches", 0.25, (64, 64)),
+        ("patches", 0.5, (64, 128)),
+        ("patches", 0.25, (64, 128)),
+        ("mosaic", None, (64, 128)),
+    ])
+    def test_matches_oracle(self, case, cutoff, hw):
+        """sensor_patches (shifted float32 adds, parity select) against the
+        stack/einsum/one-hot form; the mosaic selection is bitwise equal."""
+        h, w = hw
+        rgb = jax.random.uniform(jax.random.PRNGKey(h + w), (3, h, w, 3))
+        with jax.default_matmul_precision("float32"):
+            if case == "mosaic":
+                np.testing.assert_array_equal(
+                    np.asarray(c.mosaic(rgb)), np.asarray(_onehot_mosaic(rgb)))
+                return
+            want = _oracle_sensor_patches(rgb, cutoff, 16)
+            cfg = c.FrontendConfig(
+                image_h=h, image_w=w, aa_cutoff=cutoff,
+                patch=c.PatchSpec(patch_h=16, patch_w=16, n_vectors=8))
+            params = c.init_frontend_params(KEY, cfg)
+            got, weights = c.sensor_patches(params, rgb, cfg)
+        assert got.shape == want.shape == (3, (h // 16) * (w // 16), 256)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=0, atol=2e-6)
+        np.testing.assert_array_equal(
+            np.asarray(weights),
+            np.asarray(c.strike_columns(params["a_rgb"], 16, 16)))
+
+
 class TestSaliencyADC:
     def test_topk_fraction(self):
         scores = jax.random.uniform(KEY, (3, 64))

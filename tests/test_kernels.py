@@ -121,6 +121,31 @@ def test_quant_matmul_accuracy_vs_float():
 # sparse (active-patch-only) projection kernel
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("shape,cutoff,tb", [
+    ((3, 64, 128, 3), 0.5, None),       # one band: reflection in-block
+    ((3, 64, 128, 3), 0.25, None),
+    ((2, 64, 512, 3), 0.5, 8),          # 8 bands of 8 rows, halo tiles
+    ((2, 64, 512, 3), 0.25, 16),        # 4 bands of 16; edge lane tiles
+    ((1, 28, 20, 3), 0.5, None),        # rows no multiple of 8
+])
+def test_bayer_frame_kernel_vs_oracle(shape, cutoff, tb):
+    """The one-pass optics+mosaic kernel equals the AA filter then the
+    mosaic, for every band split and at the frame's four edges."""
+    from repro.core import bayer
+    from repro.kernels.bayer_sensor import bayer_frame_pallas
+
+    rgb = jax.random.uniform(KEY, shape)
+    if tb is None:
+        got = ops.bayer_frame(rgb, cutoff)
+    else:
+        got = bayer_frame_pallas(rgb, bayer.aa_taps(cutoff), bayer.RGGB,
+                                 tb=tb, interpret=True)
+    want = ref.bayer_frame_ref(rgb, cutoff)
+    assert got.shape == shape[:-1] and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=0, atol=1e-6)
+
+
 class TestSparseProjection:
     def _dense_gather(self, patches, w, idx, spec, **kw):
         dense = ops.ip2_project(patches, w, spec, interpret=True, **kw)

@@ -197,6 +197,30 @@ def test_scope_map_names_every_instruction(served, frames):
     assert eng.n_traces == 1
 
 
+
+def test_sensor_ops_map_to_the_sensor_scope(served, frames):
+    """Every frame-sized instruction of the compiled step (the optics
+    filter, the mosaic, the compiler's copies around them) is the sensor
+    emulation's, maps to ``sensor``, and is elementwise: no contraction
+    and no transpose of a frame."""
+    eng = _engine(served)
+    eng.admit("a")
+    text = eng.compile_step().as_text()
+    scopes = telemetry.snapshot()["scopes"]["jit_counted"]
+    entry = text[text.index("\nENTRY"):]
+    instr = re.compile(r"\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\S+)\s+([\w\-]+)\(")
+    frame = re.compile(rf"f32\[4,{H},{W}(,3)?\]")
+    found = {}
+    for line in text.splitlines():
+        m = instr.match(line)
+        if m and frame.match(m.group(2)) and not (
+                m.group(3) == "parameter" and line in entry):
+            found[m.group(1)] = m.group(3)
+    assert found
+    assert {scopes[name] for name in found} == {"sensor"}
+    assert not {"dot", "convolution", "transpose"} & set(found.values())
+    assert eng.n_traces == 1
+
 HLO = """HloModule jit_counted, is_scheduled=true
 
 %fused_computation.1 (param_0: f32[4]) -> f32[4] {
@@ -233,8 +257,25 @@ def test_scope_map_gives_compiler_instructions_a_neighbours_layer():
     assert scopes["copy.3"] == "unscoped"
 
 
+@pytest.fixture
+def no_compile_cache():
+    """A persistent compile cache that an earlier test of the process
+    turned on keys a program without its ``op_name`` metadata, so it can
+    hand a step traced without scopes the executable of the same step
+    traced with them: compile afresh here, and restore it after."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
 def test_scopes_leave_the_step_bitwise_unchanged(served, frames,
-                                                 monkeypatch):
+                                                 monkeypatch,
+                                                 no_compile_cache):
     """An engine whose step is traced without the layer scopes is the
     oracle: logits and every state leaf equal it bitwise, tick by tick,
     through churn."""

@@ -4,7 +4,8 @@ Interpret mode cannot see what the chip's compiler refuses (block shapes
 off the (8, 128) tiling, unsupported operand types), so each kernel and
 the served engine step is compiled here for a described — not attached —
 v5e chip at the ``ip2-vit`` widths: 256x256 frames, 32x32 patches (1024
-pixels), 192 vectors, k=16 of 64 patches, d_model 256, 4 heads.
+pixels), 192 vectors, k=16 of 64 patches, d_model 256, 4 heads; the
+sensor kernel also at the ``ip2-2mpix`` frames (16 slots of 1024x2048).
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time, and the test workers all
@@ -28,6 +29,7 @@ from repro.core.temporal import TemporalSpec
 from repro.kernels import ops
 from repro.kernels.vit_delta_attention import delta_attention_pallas
 from repro.models.vit import init_vit, prepare_quant_embed, vit_config_from
+from repro.serve import telemetry
 from repro.serve.engine import SaccadeEngine
 
 SLOTS, P, N2, M, K, D, H = 8, 64, 1024, 192, 16, 256, 4
@@ -89,6 +91,13 @@ def _kernel_case(name):
                     interpret=False),
                 [((SLOTS, P, N2), f32), ((M, N2), f32), ((SLOTS, K), i32),
                  ((M, D), jnp.int8), ((D,), f32), ((SLOTS,), i32)])
+    if name.startswith("bayer_frame"):
+        # the sensor's optics+mosaic; 2mpix: 16 slots of 1024x2048, in
+        # bands of 128 rows with halo tiles and edge lane tiles
+        shape = ((16, 1024, 2048, 3) if name == "bayer_frame_2mpix"
+                 else (SLOTS, 256, 256, 3))
+        return (lambda x: ops.bayer_frame(x, 0.5, interpret=False),
+                [(shape, f32)])
     if name == "quant_matmul":
         return (lambda a, sa, w8, sw: ops.quant_matmul_pre(
                     a, sa, w8, sw, interpret=False),
@@ -103,7 +112,7 @@ def _kernel_case(name):
 
 @pytest.mark.parametrize("name", [
     "ip2_project", "ip2_project_sparse", "ragged", "ragged_j3", "fused",
-    "quant_matmul", "vit_delta_attention",
+    "quant_matmul", "vit_delta_attention", "bayer_frame", "bayer_frame_2mpix",
 ])
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, args = _kernel_case(name)
@@ -132,9 +141,10 @@ def _engine(mode: str) -> SaccadeEngine:
 
 
 @pytest.mark.parametrize("mode,n_kernels", [
-    # ragged projection + w8a8 embed + delta attention on 5 of 6 layers
-    ("codes", 7),
-    ("fused", 1),
+    # the sensor's optics+mosaic, then ragged projection + w8a8 embed +
+    # delta attention on 5 of 6 layers
+    ("codes", 8),
+    ("fused", 2),
 ])
 def test_engine_step_compiles_for_v5e(one_chip, compile_kernels, mode,
                                       n_kernels):
@@ -144,7 +154,12 @@ def test_engine_step_compiles_for_v5e(one_chip, compile_kernels, mode,
     shapes = jax.tree.map(
         lambda x: _shape(one_chip, x.shape, x.dtype), args)
     compiled = eng._step_fn.lower(*shapes).compile()
-    assert compiled.as_text().count("tpu_custom_call") == n_kernels
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == n_kernels
+    # the sensor kernel is the sensor layer's in the device trace's map
+    scopes = telemetry.Telemetry().record_scopes(text)
+    sensor = [n for n in scopes if n.startswith("bayer_frame_pallas")]
+    assert sensor and {scopes[n] for n in sensor} == {"sensor"}
     # the whole step fits one chip's 16 GiB with room to spare
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 2**30
