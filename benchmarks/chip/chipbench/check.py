@@ -3,10 +3,12 @@
 Once the window has closed, the sampled cameras' whole histories are
 replayed through the configuration's plain reference: the same frames,
 and at each frame the gaze the server chose (served tokens, as a served
-model's prompt and tokens are). Per served frame this gives the largest
-logit gap to the reference, and how far the served gaze lies below the
-reference's own choice from the frame before. The reference runs in
-blocks of cameras so that it fits beside nothing else on the chip.
+model's prompt and tokens are). The reference gives, per served frame,
+its output (a pytree of arrays, as the engine's result is) and how far
+the served gaze lies below its own choice from the frame before; the
+configuration's system compares the two (``NUMBERS``, ``compare``). The
+reference runs in blocks of cameras so that it fits beside nothing else
+on the chip.
 """
 
 from __future__ import annotations
@@ -16,8 +18,6 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-NUMBERS = ("logit_gap_max", "logit_gap_median", "gaze_gap_max")
 
 
 def sample(sched, seed: int, n: int, seconds: float) -> set:
@@ -37,10 +37,12 @@ def sample(sched, seed: int, n: int, seconds: float) -> set:
     return {longest, *(int(s) for s in pick)}
 
 
-def inputs(sched, rec, sids: list, k: int, n_classes: int,
-           t_bucket: int) -> tuple[dict, np.ndarray, np.ndarray]:
-    """(reference inputs with (T, B, ...) arrays, served logits (T, B,
-    C), compared mask (T, B)) for the cameras ``sids``."""
+def inputs(sched, rec, sids: list, k: int,
+           t_bucket: int) -> tuple[dict, object, np.ndarray]:
+    """(reference inputs with (T, B, ...) arrays, served outputs with
+    (T, B, ...) leaves in the structure of the first recorded output, or
+    None where none was recorded, compared mask (T, B)) for the cameras
+    ``sids``."""
     t_len = max(len(rec.out[s]) for s in sids)
     t_len = max(t_bucket, -(-t_len // t_bucket) * t_bucket)
     b = len(sids)
@@ -49,26 +51,31 @@ def inputs(sched, rec, sids: list, k: int, n_classes: int,
           "color": np.zeros((t_len, b, 3), np.float32),
           "gaze": np.tile(np.arange(k, dtype=np.int32), (t_len, b, 1)),
           "fed": np.zeros((t_len, b), bool)}
-    logits = np.zeros((t_len, b, n_classes), np.float32)
+    first = [o for s in sids for _, _, o in rec.out[s][:1]]
+    if not first:
+        return xs, None, xs["fed"].copy()
+    leaves, tree = jax.tree.flatten(first[0])
+    bufs = [np.zeros((t_len, b) + x.shape, x.dtype) for x in leaves]
     for j, sid in enumerate(sids):
-        for t, (n, gaze, lg) in enumerate(rec.out[sid]):
+        for t, (n, gaze, out) in enumerate(rec.out[sid]):
             scene, box, colour = sched.frame_spec(sid, n)
             xs["scene"][t, j] = scene
             xs["box"][t, j] = box
             xs["color"][t, j] = colour
             xs["gaze"][t, j] = gaze
             xs["fed"][t, j] = True
-            logits[t, j] = lg
-    return xs, logits, xs["fed"].copy()
+            for buf, x in zip(bufs, jax.tree.leaves(out)):
+                buf[t, j] = x
+    return xs, jax.tree.unflatten(tree, bufs), xs["fed"].copy()
 
 
 def replay(ref_mod, conf: dict, w: dict, pool, xs: dict, precision: str,
-           block: int) -> tuple[np.ndarray, np.ndarray]:
-    """Reference logits (T, B, C) and gaze gaps (T, B), ``block`` cameras
-    at a time."""
+           block: int) -> tuple[object, np.ndarray]:
+    """Reference outputs (a pytree of (T, B, ...) leaves) and gaze gaps
+    (T, B), ``block`` cameras at a time."""
     scenes = jnp.asarray(pool)
     fn = jax.jit(lambda w, sc, x: ref_mod.replay(conf, w, sc, x, precision))
-    outs = []
+    outs, gaps = [], []
     b = xs["fed"].shape[1]
     for lo in range(0, b, block):
         part = {kk: jnp.asarray(v[:, lo:lo + block]) for kk, v in xs.items()}
@@ -77,31 +84,37 @@ def replay(ref_mod, conf: dict, w: dict, pool, xs: dict, precision: str,
             part = {kk: jnp.concatenate(
                 [v, jnp.zeros((v.shape[0], pad) + v.shape[2:], v.dtype)],
                 axis=1) for kk, v in part.items()}
-        lg, gap = fn(w, scenes, part)
-        outs.append((np.asarray(lg)[:, :block - pad],
-                     np.asarray(gap)[:, :block - pad]))
-    return (np.concatenate([o[0] for o in outs], axis=1),
-            np.concatenate([o[1] for o in outs], axis=1))
+        out, gap = fn(w, scenes, part)
+        outs.append(jax.tree.map(lambda x: np.asarray(x)[:, :block - pad],
+                                 out))
+        gaps.append(np.asarray(gap)[:, :block - pad])
+    return (jax.tree.map(lambda *p: np.concatenate(p, axis=1), *outs),
+            np.concatenate(gaps, axis=1))
 
 
-def numbers(served, ref, gaps, mask) -> dict:
-    """The compared numbers over the frames in ``mask``."""
-    per_frame = np.max(np.abs(served - ref), axis=-1)[mask]
-    finite = bool(np.all(np.isfinite(served[mask])))
-    if not finite or per_frame.size == 0:
-        return {"logit_gap_max": math.inf, "logit_gap_median": math.inf,
-                "gaze_gap_max": math.inf, "frames": int(per_frame.size)}
-    return {"logit_gap_max": float(np.max(per_frame)),
-            "logit_gap_median": float(np.median(per_frame)),
-            "gaze_gap_max": float(np.max(gaps[mask])),
-            "frames": int(per_frame.size)}
+def check_limits(limits: dict, names) -> None:
+    """Refuse a checks file that lacks a limit for one of the system's
+    compared numbers, or the least number of frames compared."""
+    missing = sorted((set(names) | {"min_frames"}) - set(limits))
+    if missing:
+        raise SystemExit(f"chipbench: the checks file has no limit for "
+                         f"{missing}")
 
 
-def verdict(nums: dict, limits: dict) -> tuple[bool, dict]:
-    """(correct, {name: {"value", "limit"}}): every number at or under its
-    limit, and at least ``min_frames`` frames compared."""
-    shown = {k: {"value": nums[k], "limit": limits[k]} for k in NUMBERS}
+def compare(system, served, ref, gaps, mask) -> dict:
+    """The system's compared numbers; each infinite, over no frames,
+    where no sampled frame was served."""
+    if served is None:
+        return {**dict.fromkeys(system.NUMBERS, math.inf), "frames": 0}
+    return system.compare(served, ref, gaps, mask)
+
+
+def verdict(nums: dict, limits: dict, names) -> tuple[bool, dict]:
+    """(correct, {name: {"value", "limit"}}): each of the numbers
+    ``names`` at or under its limit, and at least ``min_frames`` frames
+    compared."""
+    shown = {k: {"value": nums[k], "limit": limits[k]} for k in names}
     shown["frames"] = {"value": nums["frames"], "limit": limits["min_frames"]}
     ok = (nums["frames"] >= limits["min_frames"]
-          and all(nums[k] <= limits[k] for k in NUMBERS))
+          and all(nums[k] <= limits[k] for k in names))
     return ok, shown

@@ -1,5 +1,7 @@
 """The yardstick's arithmetic: published chip peaks, and the operations
-and bytes each kernel and a whole served frame need, from shapes alone.
+and bytes each kernel needs, from shapes alone. (The operations of a
+whole served frame depend on the backend: ``frame_ops`` of
+``systems/<name>.py``.)
 
 Peaks: Google Cloud documentation, "TPU v5e" (system architecture):
 197 TFLOP/s bf16, 393 TOP/s int8, 16 GiB of HBM at 819 GB/s. A device
@@ -59,25 +61,3 @@ def delta_attention_min(q_rows: int, k: int, d_model: int,
     return {"flops": n_heads * 2.0 * 2.0 * q_rows * k * dh,
             "bytes": (2.0 * q_rows + 2.0 * k) * n_heads * dh * 4.0
             + k * 4.0}
-
-
-def backend_macs(n_tokens: int, n_layers: int, m: int, d: int, d_ff: int,
-                 n_classes: int) -> float:
-    """MACs of the dense backend on ``n_tokens`` tokens: embed, per layer
-    Q/K/V, attention scores and mix over ``n_tokens`` keys, output
-    projection and MLP, then the head."""
-    per_layer = n_tokens * (3.0 * d * d) + n_tokens * (
-        2.0 * n_tokens * d + d * d + 2.0 * d * d_ff)
-    return n_tokens * m * d + n_layers * per_layer + n_classes * d
-
-
-def frame_ops(conf: dict, k: int) -> dict:
-    """Model operations of one served frame at full recompute, split by
-    the precision they run at: the projection and the backend in float
-    (priced at bf16), the embed in int8."""
-    n2 = conf["patch"] ** 2
-    m, d = conf["n_vectors"], conf["d_model"]
-    embed = 2.0 * k * m * d
-    dense = 2.0 * backend_macs(k, conf["n_layers"], m, d, conf["d_ff"],
-                               conf["n_classes"])
-    return {"float": 2.0 * k * n2 * m + dense - embed, "int8": embed}

@@ -2,9 +2,10 @@
 host-path spans and counters over the untraced window, the device time of
 its layer scopes in the traced window, and its spans on the trace's clock.
 
-The program is asked for its telemetry only when a reader runs, after the
-window. A program without that module (an older commit) gives every
-reader here nothing to read: they return None and do not raise.
+The program is asked for its telemetry, through the system under test
+(``ctx.system.telemetry()``), only when a reader runs, after the window.
+A program without telemetry (an older commit) gives every reader here
+nothing to read: they return None and do not raise.
 """
 
 from __future__ import annotations
@@ -24,13 +25,9 @@ ENGINE_CHILDREN = ("engine.stage", "engine.churn_flush", "engine.upload",
 MAP_SLACK_S = 50e-6
 
 
-def snapshot():
+def snapshot(ctx):
     """The program's telemetry snapshot, or None where it has none."""
-    try:
-        from repro.serve import telemetry
-    except ImportError:
-        return None
-    return telemetry.snapshot()
+    return ctx.system.telemetry()
 
 
 def window_rows(ctx, snap=None):
@@ -38,7 +35,7 @@ def window_rows(ctx, snap=None):
     ``time.perf_counter``) that start in the untraced window,
     ``ctx.t0 <= start < ctx.t_cut``; None without telemetry, or where the
     ring has overwritten rows that may have started in the window."""
-    snap = snapshot() if snap is None else snap
+    snap = snapshot(ctx) if snap is None else snap
     if snap is None:
         return None
     sp = snap["spans"]
@@ -113,7 +110,7 @@ def scope_device_s(ctx) -> dict | None:
     devices; None without device ops, a window or a scope map."""
     if ctx.trace is None or not any(ctx.trace["ops"]):
         return None
-    snap = snapshot()
+    snap = snapshot(ctx)
     if snap is None or STEP_PROGRAM not in snap["scopes"]:
         return None
     scopes = snap["scopes"][STEP_PROGRAM]
@@ -159,7 +156,7 @@ def to_trace_clock(ctx, snap=None) -> dict | None:
     constant distance to the spans' starts. Refuses where any mapped
     ``engine.step`` lies outside its ``stage_dispatch`` by more than
     50 us, or a traced tick has no ``engine.step``."""
-    snap = snapshot() if snap is None else snap
+    snap = snapshot(ctx) if snap is None else snap
     if snap is None or ctx.trace is None:
         return None
     sd = np.asarray([(s, e) for n, s, e in ctx.trace["spans"]
@@ -198,7 +195,7 @@ def idle_attribution(ctx, snap=None) -> dict | None:
     :func:`to_trace_clock`), else the harness phase, else ``other``; and
     beside it each engine span's mean ms in traced and in untraced
     ticks. None where the spans cannot be placed on the trace's clock."""
-    snap = snapshot() if snap is None else snap
+    snap = snapshot(ctx) if snap is None else snap
     offsets = to_trace_clock(ctx, snap)
     if offsets is None or not ctx.trace["ops"]:
         return None

@@ -122,8 +122,10 @@ def run(args, root: str, here: str = spec.HERE, t_start: float | None = None,
     mets = spec.metrics_for(bench, cell["name"], bool(args.trace))
     readers = {m["name"]: spec.reader(m["name"], here) for m in mets}
     ref = spec.reference(conf["reference"], here)
+    system = spec.system(conf["reference"], here)
     # what the harness does not implement is refused before any run
-    model.check_config(conf)
+    system.check_config(conf)
+    check.check_limits(limits, system.NUMBERS)
     traffic.check_keys(mix)
     device = device_check(cell["chips"])
     marks.append(("devices", time.perf_counter()))
@@ -153,7 +155,7 @@ def run(args, root: str, here: str = spec.HERE, t_start: float | None = None,
     sample = check.sample(sched, args.seed, mix["check_streams"],
                           preroll + seconds)
     marks.append(("traffic_scenes", time.perf_counter()))
-    params = model.make_weights(conf, model.seed_key(args.seed))
+    params = system.make_weights(conf, model.seed_key(args.seed))
     jax.block_until_ready(params)
     marks.append(("weights", time.perf_counter()))
     t_weights = time.perf_counter() - t_start
@@ -168,8 +170,9 @@ def run(args, root: str, here: str = spec.HERE, t_start: float | None = None,
     spans = serving.Spans(bool(args.trace))
     rec = serving.Record(sample)
     with jax.default_matmul_precision(conf["matmul_precision"]):
-        eng = model.make_engine(conf, params, capacity=n_streams,
-                                chips=cell["chips"])
+        eng = system.make_engine(
+            conf, params, capacity=n_streams,
+            mesh=model.slot_mesh(cell["chips"], n_streams))
         kernels = (trace.kernel_map(eng.compile_step().as_text())
                    if args.trace else {})
         fed = (range(1, n_streams + 1) if sched.loop == "open"
@@ -214,10 +217,10 @@ def run(args, root: str, here: str = spec.HERE, t_start: float | None = None,
                      "idle_gaps": trace.idle_gaps(tr)}
         shutil.rmtree(trace_dir, ignore_errors=True)
 
-    ctx = Ctx(conf=conf, mix=mix, sizes=sz, rec=rec, t0=t0, seconds=seconds,
-              setup_s=setup_s, window_compiles=compiles["n"], trace=tr,
-              kernels=kernels, device=device, loop=sched.loop,
-              streams=n_streams,
+    ctx = Ctx(system=system, conf=conf, mix=mix, sizes=sz, rec=rec, t0=t0,
+              seconds=seconds, setup_s=setup_s,
+              window_compiles=compiles["n"], trace=tr, kernels=kernels,
+              device=device, loop=sched.loop, streams=n_streams,
               t_cut=(t0 + tw.start - tw.settle) if args.trace else math.inf)
     metrics = {}
     for m in mets:
@@ -229,17 +232,17 @@ def run(args, root: str, here: str = spec.HERE, t_start: float | None = None,
     t_ref = time.perf_counter()
     sids = sorted(sample)
     xs, served, mask = check.inputs(sched, rec, sids, sz["k"],
-                                    conf["n_classes"], mix["check_t_bucket"])
-    w = model.reference_weights(params)
-    ref_logits, gaps = check.replay(ref, conf, w, pool, xs, "float32",
-                                    mix["check_block"])
-    nums = check.numbers(served, ref_logits, gaps, mask)
-    correct, shown = check.verdict(nums, limits)
+                                    mix["check_t_bucket"])
+    w = system.reference_weights(params)
+    ref_out, gaps = check.replay(ref, conf, w, pool, xs, "float32",
+                                 mix["check_block"])
+    nums = check.compare(system, served, ref_out, gaps, mask)
+    correct, shown = check.verdict(nums, limits, system.NUMBERS)
     control = None
     if getattr(args, "control", 0):
-        lo_logits, lo_gaps = check.replay(ref, conf, w, pool, xs, "bf16x3",
-                                          mix["check_block"])
-        control = check.numbers(lo_logits, ref_logits, lo_gaps, mask)
+        lo_out, lo_gaps = check.replay(ref, conf, w, pool, xs, "bf16x3",
+                                       mix["check_block"])
+        control = check.compare(system, lo_out, ref_out, lo_gaps, mask)
     ref_s = time.perf_counter() - t_ref
 
     attempted = len(rec.due) + rec.dropped
@@ -248,7 +251,7 @@ def run(args, root: str, here: str = spec.HERE, t_start: float | None = None,
     order = np.argsort(rec.due)
     stale = np.concatenate(rec.n_stale) if rec.n_stale else np.zeros(0)
     macs = np.concatenate(rec.macs) if rec.macs else np.zeros(0)
-    head = conf["n_classes"] * conf["d_model"]      # MACs of the head alone
+    head = system.head_macs(conf)
     info = {
         "cell": cell["name"], "seed": args.seed, "trace": int(args.trace),
         "device": device["kind"], "streams": n_streams,

@@ -111,7 +111,7 @@ def w8a8_embed_roofline(ctx):
 def delta_attn_roofline(ctx):
     c, k = ctx.conf, ctx.sizes["k"]
     pk = _pk(ctx)
-    head = c["n_classes"] * c["d_model"]
+    head = ctx.system.head_macs(c)
 
     def least(i):
         # a slot whose frame changed re-attends every query (exact reuse)
@@ -130,6 +130,6 @@ def step_mfu(ctx):
     if fps is None or ctx.trace is None:
         return None
     pk = _pk(ctx)
-    ops = costs.frame_ops(ctx.conf, ctx.sizes["k"])
+    ops = ctx.system.frame_ops(ctx.conf, ctx.sizes)
     return 100.0 * fps * (ops["float"] / pk["bf16_flops"]
                           + ops["int8"] / pk["int8_ops"])

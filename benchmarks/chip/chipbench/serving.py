@@ -1,12 +1,12 @@
 """The serving loop: continuous batching of camera frames, as a user's
 server would run it, with every time taken on the host clock.
 
-Each tick, as soon as the previous tick's logits are on the host and at
+Each tick, as soon as the previous tick's outputs are on the host and at
 least one frame is due, the loop hands the engine every due frame, oldest
 first and one per camera, with ``step(frames, block=False)``, then
-fetches the logits with ``handle.result()`` and the gaze each camera is
+fetches the outputs with ``handle.result()`` and the gaze each camera is
 told to convert next (the served indices). A frame's latency runs from
-the moment it was due to the moment its logits are on the host. Churn
+the moment it was due to the moment its output is on the host. Churn
 (evict, then admit) is applied between ticks at its scheduled time, or
 once the evicted cameras' last frames are served, where they are still
 queued then.
@@ -50,7 +50,9 @@ class Record:
         # per tick: (start, step called, step returned, results in, fed)
         self.ticks = []
         self.gen_s = []                  # host seconds spent making frames
-        self.out = {s: [] for s in sample}   # sid -> [(n, gaze, logits)]
+        # sid -> [(n, gaze, output)]: the output as ``result()`` gives it,
+        # a pytree of arrays (the logits alone are a one-leaf pytree)
+        self.out = {s: [] for s in sample}
         # per tick, over the fed slots: recomputed patches, backend MACs
         self.n_stale, self.macs = [], []
         # frames due in the window and never served
@@ -92,7 +94,7 @@ def tick(eng, frames: dict, rec: Record, spans: Spans, t_start: float,
             rec.done.append(t_done)
         if sid in rec.sample:
             rec.out[sid].append((n, gaze[eng.slot_of(sid)].copy(),
-                                 np.asarray(out[sid]).copy()))
+                                 jax.tree.map(np.array, out[sid])))
 
 
 def make_frame(sched, pool, sid: int, n: int) -> np.ndarray:

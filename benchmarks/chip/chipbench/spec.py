@@ -5,6 +5,8 @@ per-cell check or metric sits in a file of its own, found by the name
   configs/<config>.json     sizes, precision, serving mode; names its
                             plain reference
   references/<name>.py      a plain reference (``replay``, ``sizes``)
+  systems/<name>.py         the system under test of that reference: the
+                            only place the program is imported
   traffic/<traffic>.json    parameters of the one traffic generator
   checks/<cell>.json        the limits of the comparison for one cell
   metrics/<metric>.py       a reader: ``read(ctx) -> float | None``
@@ -58,6 +60,15 @@ def _module(path: str, name: str):
 def reference(name: str, here: str = HERE):
     return _module(os.path.join(here, "references", f"{name}.py"),
                    f"chipbench_reference_{name}")
+
+
+def system(name: str, here: str = HERE):
+    """The system under test of the reference ``name``: ``check_config``,
+    ``make_weights``, ``reference_weights``, ``make_engine``,
+    ``telemetry``, ``frame_ops``, ``head_macs``, ``NUMBERS`` and
+    ``compare``."""
+    return _module(os.path.join(here, "systems", f"{name}.py"),
+                   f"chipbench_system_{name}")
 
 
 def reader(metric: str, here: str = HERE):

@@ -2,10 +2,11 @@
 
 They cover the trace reduction on a hand-built trace, the operation and
 byte counts against the program's own counters, discovery of a config,
-traffic mix, check and metric added as files, whole runs of a tiny cell
-with the device check skipped (kernels in interpret mode), the open
-loop's churn and failure accounting on a simulated clock, and that the
-comparison fails the lower-precision control and a broken timed path.
+traffic mix, check and metric added as files, a second backend added as
+files only, whole runs of a tiny cell with the device check skipped
+(kernels in interpret mode), the open loop's churn and failure accounting
+on a simulated clock, and that the comparison fails the lower-precision
+control, a broken timed path and a perturbed output.
 """
 
 from __future__ import annotations
@@ -101,8 +102,9 @@ def test_backend_macs_match_program_counter(conf_name):
 
     c = spec.config(conf_name)
     k = check_k(c)
-    ours = costs.backend_macs(k, c["n_layers"], c["n_vectors"], c["d_model"],
-                              c["d_ff"], c["n_classes"])
+    ours = spec.system(c["reference"]).backend_macs(
+        k, c["n_layers"], c["n_vectors"], c["d_model"], c["d_ff"],
+        c["n_classes"])
     assert ours == dense_backend_macs(k, c["n_layers"], c["n_vectors"],
                                       c["d_model"], c["d_ff"], c["n_classes"])
 
@@ -228,8 +230,10 @@ def test_control_fails_at_ip2_vit_widths(cell):
 
     conf = spec.config("ip2-vit")
     ref = spec.reference(conf["reference"])
+    system = spec.system(conf["reference"])
     k = ref.sizes(conf)["k"]
-    w = model.reference_weights(model.make_weights(conf, model.seed_key(5)))
+    w = system.reference_weights(system.make_weights(conf,
+                                                     model.seed_key(5)))
     pool = scenes.scene_pool(5, 4, conf["frame_h"], conf["frame_w"])
     b, t_len = 2, 16
     fed = jnp.ones((b,), bool)
@@ -254,9 +258,10 @@ def test_control_fails_at_ip2_vit_widths(cell):
           "fed": np.ones((t_len, b), bool)}
     hi, gaps = check.replay(ref, conf, w, pool, xs, "float32", b)
     lo, lo_gaps = check.replay(ref, conf, w, pool, xs, "bf16x3", b)
-    assert check.numbers(hi, hi, gaps, xs["fed"])["gaze_gap_max"] == 0.0
-    control = check.numbers(lo, hi, lo_gaps, xs["fed"])
-    ok, _ = check.verdict(control, {**spec.limits(cell), "min_frames": 1})
+    assert system.compare(hi, hi, gaps, xs["fed"])["gaze_gap_max"] == 0.0
+    control = system.compare(lo, hi, lo_gaps, xs["fed"])
+    ok, _ = check.verdict(control, {**spec.limits(cell), "min_frames": 1},
+                          system.NUMBERS)
     assert not ok, control
 
 
@@ -309,13 +314,163 @@ def test_broken_timed_path_is_not_correct(tiny_root, monkeypatch, fault):
     assert not res["correct"], res["check"]
 
 
+PROBS_SYSTEM = """
+\"\"\"A second backend: the ip2_saccade engine, whose result gives per frame
+its logits and its class probabilities (plus ``OFFSET``).\"\"\"
+
+import math
+import os
+import types
+
+import numpy as np
+
+from chipbench import spec
+
+base = spec.system("ip2_saccade", os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+make_weights, reference_weights = base.make_weights, base.reference_weights
+telemetry, frame_ops = base.telemetry, base.frame_ops
+head_macs = base.head_macs
+NUMBERS = ("logits_gap_max", "probs_gap_max", "gaze_gap_max")
+OFFSET = {offset}
+
+
+def check_config(conf):
+    if conf.get("serve_probs") is not True:
+        raise SystemExit("probs: serve_probs must be true")
+    base.check_config({{k: v for k, v in conf.items() if k != "serve_probs"}})
+
+
+def softmax(x):
+    e = np.exp(x - np.max(x, axis=-1, keepdims=True))
+    return e / np.sum(e, axis=-1, keepdims=True)
+
+
+class Engine:
+    def __init__(self, eng):
+        self.eng = eng
+
+    def __getattr__(self, name):
+        return getattr(self.eng, name)
+
+    def step(self, frames, block=False):
+        handle = self.eng.step(frames, block=block)
+        return types.SimpleNamespace(result=lambda: {{
+            sid: {{"logits": lg, "probs": softmax(lg) + OFFSET}}
+            for sid, lg in handle.result().items()}})
+
+
+def make_engine(conf, params, capacity, mesh=None):
+    return Engine(base.make_engine(conf, params, capacity, mesh))
+
+
+def compare(served, ref, gaps, mask):
+    if not mask.any():
+        return {{**dict.fromkeys(NUMBERS, math.inf), "frames": 0}}
+    gap = lambda k: float(np.max(np.abs(served[k] - ref[k])[mask]))
+    return {{"logits_gap_max": gap("logits"), "probs_gap_max": gap("probs"),
+            "gaze_gap_max": float(np.max(gaps[mask])),
+            "frames": int(mask.sum())}}
+"""
+
+PROBS_REFERENCE = """
+\"\"\"The ip2_saccade reference, with each frame's class probabilities.\"\"\"
+
+import os
+
+import jax
+
+from chipbench import spec
+
+base = spec.reference("ip2_saccade", os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+sizes = base.sizes
+
+
+def replay(conf, w, scenes, xs, precision):
+    logits, gaps = base.replay(conf, w, scenes, xs, precision)
+    return {"logits": logits, "probs": jax.nn.softmax(logits, axis=-1)}, gaps
+"""
+
+
+@pytest.fixture(scope="module")
+def probs_root(tiny_root, tmp_path_factory):
+    """A copy of the tiny benchmark with a second backend added as files
+    only: ``systems/probs.py`` and ``references/probs.py`` (and the same
+    system with its probabilities perturbed by 0.01, ``probs_off``), a
+    config with a key ``ip2_saccade`` does not implement, checks files
+    with the system's own compared numbers, and a cell for each."""
+    root = str(tmp_path_factory.mktemp("probs")) + "/bench"
+    shutil.copytree(tiny_root, root, symlinks=True)
+    here = os.path.join(root, "benchmarks", "chip")
+    bench = spec.benchmark(root)
+    for name, offset in (("probs", 0.0), ("probs_off", 0.01)):
+        with open(os.path.join(here, "systems", f"{name}.py"), "w") as f:
+            f.write(PROBS_SYSTEM.format(offset=offset))
+        with open(os.path.join(here, "references", f"{name}.py"), "w") as f:
+            f.write(PROBS_REFERENCE)
+        _dump(os.path.join(here, "configs", f"tiny_{name}.json"),
+              {**spec.config("tiny", here), "serve_probs": True,
+               "reference": name})
+        _dump(os.path.join(here, "checks", f"tiny.{name}.json"),
+              {"logits_gap_max": 7e-3, "probs_gap_max": 1e-3,
+               "gaze_gap_max": 5e-5, "min_frames": 5})
+        bench["workloads"].append(
+            {"name": f"tiny.{name}", "config": f"tiny_{name}",
+             "traffic": "tiny_open", "chips": 1, "why": "a second backend"})
+    _dump(os.path.join(root, "BENCHMARK.json"), bench)
+    return root
+
+
+@pytest.mark.parametrize("case", ["served", "perturbed", "under_ip2_saccade"])
+def test_new_backend_is_added_as_files_only(probs_root, case):
+    """A backend whose served output is a pytree runs a tiny cell
+    ``correct`` on its own compared numbers; the same backend with one
+    output leaf perturbed does not; and its config, which asks for a key
+    ``ip2_saccade`` does not implement, is still refused under
+    ``ip2_saccade`` before the device is touched."""
+    if case == "under_ip2_saccade":
+        here = os.path.join(probs_root, "benchmarks", "chip")
+        conf = spec.config("tiny_probs", here)
+        _dump(os.path.join(here, "configs", "tiny_probs.json"),
+              {**conf, "reference": "ip2_saccade"})
+        touched = []
+        args = types.SimpleNamespace(workload="tiny.probs", seed=3,
+                                     seconds=1.0, trace=0, streams=None,
+                                     control=0)
+        try:
+            with pytest.raises(SystemExit):
+                harness.run(args, probs_root, here,
+                            device_check=lambda n: touched.append(n))
+        finally:
+            _dump(os.path.join(here, "configs", "tiny_probs.json"), conf)
+        assert touched == []
+        return
+    res = _run(probs_root, "tiny.probs" if case == "served"
+               else "tiny.probs_off", seconds=1.0)
+    assert list(res["check"]) == ["logits_gap_max", "probs_gap_max",
+                                  "gaze_gap_max", "frames"]
+    assert res["check"]["frames"]["value"] >= 5
+    assert res["correct"] == (case == "served"), res["check"]
+    assert res["check"]["logits_gap_max"]["value"] <= 7e-3
+
+
 FOUR_CHIPS = """
 import json, sys, types
 sys.path[:0] = [{here!r}, {src!r}]
-from chipbench import harness, model
+from chipbench import harness, spec
 made = []
-real = model.make_engine
-model.make_engine = lambda *a, **k: made.append(real(*a, **k)) or made[-1]
+real = spec.system
+
+
+def system(*a, **k):
+    mod = real(*a, **k)
+    make = mod.make_engine
+    mod.make_engine = lambda *a, **k: made.append(make(*a, **k)) or made[-1]
+    return mod
+
+
+spec.system = system
 args = types.SimpleNamespace(workload="tiny.four", seed=2**33 + 1,
                              seconds=1.0, trace=0, streams=None, control=0)
 res = harness.run(args, {root!r}, {here!r}, device_check=lambda n: dict(
@@ -373,11 +528,14 @@ def test_slots_that_do_not_divide_are_refused():
     ("serving", "rollout_ticks", 16),
     ("mix", "burst_share", 0.5),
     ("mix", "loop", "diurnal"),
+    ("checks", "gaze_gap_max", None),
 ])
 def test_unimplemented_keys_are_refused(tiny_root, where, key, value):
     """A config or mix that asks for what the harness does not implement
-    (another serving mode, a governor, an unknown key) stops the run
-    before the device is touched, instead of running something else."""
+    (another serving mode, a governor, an unknown key), or a checks file
+    without a limit for one of the system's compared numbers, stops the
+    run before the device is touched, instead of running something
+    else."""
     here = os.path.join(tiny_root, "benchmarks", "chip")
     conf = spec.config("tiny", here)
     mix = spec.traffic("tiny_open", here)
@@ -394,8 +552,10 @@ def test_unimplemented_keys_are_refused(tiny_root, where, key, value):
                                "traffic": "refused", "chips": 1,
                                "why": "asks for what is not implemented"})
     _dump(os.path.join(tiny_root, "BENCHMARK.json"), bench)
-    _dump(os.path.join(here, "checks", "tiny.refused.json"),
-          spec.limits("tiny.open", here))
+    limits = spec.limits("tiny.open", here)
+    if where == "checks":
+        del limits[key]
+    _dump(os.path.join(here, "checks", "tiny.refused.json"), limits)
     touched = []
     args = types.SimpleNamespace(workload="tiny.refused", seed=3, seconds=1.0,
                                  trace=0, streams=None, control=0)

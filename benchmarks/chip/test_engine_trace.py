@@ -89,7 +89,7 @@ def _trace():
 @pytest.fixture
 def filled(monkeypatch):
     snap = _snapshot()
-    monkeypatch.setattr(engine_trace, "snapshot", lambda: snap)
+    monkeypatch.setattr(engine_trace, "snapshot", lambda ctx: snap)
     return snap
 
 
@@ -118,11 +118,11 @@ def test_host_readers_refuse_a_ring_that_lost_the_window(monkeypatch,
     read = spec.reader(metric)
     # rows were lost, and the oldest kept ended after the window opened
     monkeypatch.setattr(engine_trace, "snapshot",
-                        lambda: _snapshot(ROWS[2:], dropped=2))
+                        lambda ctx: _snapshot(ROWS[2:], dropped=2))
     assert read(_ctx()) is None
     # rows were lost, all of them before the window
     monkeypatch.setattr(engine_trace, "snapshot",
-                        lambda: _snapshot(ROWS[1:], dropped=1))
+                        lambda ctx: _snapshot(ROWS[1:], dropped=1))
     assert read(_ctx()) == pytest.approx(HOST[metric])
 
 
@@ -149,7 +149,7 @@ def test_device_readers_without_device_ops(filled, metric):
 def test_readers_without_telemetry(monkeypatch, metric):
     """A program that has no telemetry (an older commit) gives each
     reader nothing to read."""
-    monkeypatch.setattr(engine_trace, "snapshot", lambda: None)
+    monkeypatch.setattr(engine_trace, "snapshot", lambda ctx: None)
     assert spec.reader(metric)(_ctx(_trace())) is None
 
 
